@@ -8,8 +8,9 @@ the test suite and the benches cannot drift):
   SERVING_PERF_STRICT  — keep the concurrency-gain perf gates hard
                          (default on; hosted runners set 0 to demote the
                          host-headroom-dependent gates to skips).
-  PALLAS_INTERPRET     — force the Pallas kernels' interpret mode on/off
-                         (default: auto from the jax backend).
+  PALLAS_INTERPRET     — Pallas interpret mode; follows the jax backend
+                         (interpreted off a TPU, compiled on one) and a
+                         value that contradicts it is an error.
 """
 from __future__ import annotations
 
@@ -49,6 +50,15 @@ def pallas_interpret() -> Optional[bool]:
     from repro.kernels.runtime import parse_interpret_env
 
     return parse_interpret_env(os.environ.get("PALLAS_INTERPRET"))
+
+
+def begin() -> None:
+    """First call of every bench entry point: place JAX's persistent
+    compile cache (``repro.compile_cache``) and print the CSV header."""
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
+    print("name,us_per_call,derived")
 
 
 def timeit(fn: Callable, iters: int = 3, warmup: int = 1) -> float:

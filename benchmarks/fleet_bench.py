@@ -22,7 +22,7 @@ from __future__ import annotations
 import os
 from pathlib import Path
 
-from benchmarks.common import emit_json, quick, row
+from benchmarks.common import begin, emit_json, quick, row
 from repro.experiments.fleet import FLEET_ITERS, FLEET_WINDOW, run_fleet
 from repro.experiments.report import fleet_convergence_figure
 from repro.experiments.schema import validate_fleet_record
@@ -98,5 +98,5 @@ def bench_fleet_suite() -> dict:
 
 
 if __name__ == "__main__":
-    print("name,us_per_call,derived")
+    begin()
     bench_fleet_suite()

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit_json, quick, row, timeit
+from benchmarks.common import begin, emit_json, quick, row, timeit
 from repro.core.dcov import (
     dcor,
     dcor_all,
@@ -47,10 +47,10 @@ def bench_dcov_kernel(record: dict | None = None):
     row("dcov_core_jnp_n512", us_core, "model-side jnp implementation")
     if record is not None:
         record["dcov_pallas_n512"] = {"us": us_pallas, "err_vs_ref": err}
-    # ORACLE-scale: beyond one VMEM tile the auto-blocked kernel (8×8
-    # grid of 256-tiles here) must stay correct, not degrade to an
+    # ORACLE-scale: beyond one VMEM tile the auto-blocked kernel (16×16
+    # grid of 128-tiles here) must stay correct, not degrade to an
     # oversized single tile. Timing is interpret-mode (correctness gate
-    # only); one rep keeps the 64-step grid walk affordable in CI.
+    # only); one rep keeps the 256-step grid walk affordable in CI.
     n2 = 2048
     x2 = jnp.asarray(rng.normal(size=n2), jnp.float32)
     y2 = jnp.asarray(np.asarray(x2) ** 2 + rng.normal(size=n2) * 0.1, jnp.float32)
@@ -329,6 +329,6 @@ def bench_kernels_suite():
 
 
 if __name__ == "__main__":
-    print("name,us_per_call,derived")
+    begin()
     bench_kernels_suite()
     bench_analytics_suite()

@@ -27,7 +27,7 @@ from __future__ import annotations
 import time
 from pathlib import Path
 
-from benchmarks.common import emit_json, quick, row
+from benchmarks.common import begin, emit_json, quick, row
 
 MATRIX_JSON = Path(__file__).resolve().parent.parent / "BENCH_matrix.json"
 MATRIX_MD = MATRIX_JSON.with_suffix(".md")
@@ -409,7 +409,7 @@ def bench_matrix_suite():
 
 
 def main() -> None:
-    print("name,us_per_call,derived")
+    begin()
     bench_matrix_suite()
 
 

@@ -11,6 +11,7 @@ def main() -> None:
         pod_tuning,
         serving_bench,
     )
+    from benchmarks.common import begin
 
     benches = [
         paper_figs.bench_fig1_tradeoff,
@@ -30,7 +31,7 @@ def main() -> None:
         ablations.bench_ablation_step_floor,
         ablations.bench_ablation_probe_policy,
     ]
-    print("name,us_per_call,derived")
+    begin()
     failures = 0
     for b in benches:
         try:

@@ -22,7 +22,7 @@ import time
 
 import numpy as np
 
-from benchmarks.common import row
+from benchmarks.common import begin, row
 
 
 def force_lanes() -> None:
@@ -82,6 +82,6 @@ def smoke_fleet() -> None:
 
 if __name__ == "__main__":
     force_lanes()
-    print("name,us_per_call,derived")
+    begin()
     smoke_static_cell()
     smoke_fleet()

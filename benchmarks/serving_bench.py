@@ -8,24 +8,16 @@ from __future__ import annotations
 
 from pathlib import Path
 
-from benchmarks.common import emit_json, quick, row
+from benchmarks.common import begin, emit_json, quick, row
 
 QUICK = quick()
 
 
 def _engine(batch_size: int = 2, max_len: int = 64):
-    import jax
+    from repro.launch.serve import build_engine
 
-    from repro.configs.registry import get_config
-    from repro.configs.runtime import RunConfig
-    from repro.models.transformer import ApplyCtx, init_model_params
-    from repro.serving import ServingEngine
-
-    cfg = get_config("qwen2.5-3b").reduced()
-    rcfg = RunConfig(remat="none", moe_impl="dense")
-    ctx = ApplyCtx(cfg, rcfg, None)
-    params = init_model_params(jax.random.PRNGKey(0), cfg, rcfg)
-    return ServingEngine(ctx, params, batch_size=batch_size, max_len=max_len), cfg
+    engine = build_engine("qwen2.5-3b", batch_size, max_len)
+    return engine, engine.ctx.cfg
 
 
 def bench_serving_suite():
@@ -86,7 +78,7 @@ def bench_serving_suite():
 
 
 def main() -> None:
-    print("name,us_per_call,derived")
+    begin()
     bench_serving_suite()
 
 

@@ -11,27 +11,19 @@ per-interval trace and BENCH_serving.json.
 """
 import json
 
-import jax
-
-from repro.configs.registry import get_config
-from repro.configs.runtime import RunConfig
 from repro.core import tpu_pod_space
 from repro.device.measure import analytic_scale_and_power
-from repro.models.transformer import ApplyCtx, init_model_params
+from repro.launch.serve import build_engine
 from repro.serving import (
     ServingController,
-    ServingEngine,
     ServingRuntime,
     build_serving_record,
     measure_concurrency_curve,
     workload,
 )
 
-cfg = get_config("qwen2.5-3b").reduced()
-rcfg = RunConfig(remat="none", moe_impl="dense")
-ctx = ApplyCtx(cfg, rcfg, None)
-params = init_model_params(jax.random.PRNGKey(0), cfg, rcfg)
-engine = ServingEngine(ctx, params, batch_size=2, max_len=64)
+engine = build_engine("qwen2.5-3b", batch=2, max_len=64)
+cfg = engine.ctx.cfg
 space = tpu_pod_space()
 
 # 1) measured τ vs concurrency — identical workload per level, the knob is

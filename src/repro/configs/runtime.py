@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
 _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
@@ -27,7 +28,9 @@ class RunConfig:
     capacity_factor: float = 1.25
     # decode
     long_context_window: int = 8192
-    use_pallas: bool = False  # TPU deployment flag; CPU CI uses XLA path
+    # Pallas prefill attention / SSD kernels; ``serving_config`` sets it
+    # on a TPU backend, training and the CPU tests keep the XLA path
+    use_pallas: bool = False
     # decode-time tensor-parallel mode: replicate the (small) activations
     # over the data axes and let the embed-dim contraction reduce with an
     # activation all-reduce, instead of fsdp-gathering the weights every
@@ -41,3 +44,14 @@ class RunConfig:
     @property
     def cdtype(self):
         return _DTYPES[self.compute_dtype]
+
+
+def serving_config(**overrides) -> RunConfig:
+    """The serving path's RunConfig: no remat, dense MoE, and the Pallas
+    prefill kernels exactly when the backend is a TPU, so a chip never
+    serves through the XLA attention by default."""
+    kw = dict(
+        remat="none", moe_impl="dense", use_pallas=jax.default_backend() == "tpu"
+    )
+    kw.update(overrides)
+    return RunConfig(**kw)

@@ -13,7 +13,7 @@ Two independent tools, both zero-cost when off:
 
 - **compile-count guard**: :func:`count_compiles` captures
   ``jax.log_compiles`` output and counts executable builds per jitted
-  function name. One ``Compiling <name> with global shapes`` line is
+  function name. One ``Compiling jit(<name>) with global shapes`` line is
   emitted per build — including persistent-compilation-cache hits
   (deserialization still lowers), and never on in-process jit cache
   reuse — so "the static matrix compiles exactly once per engine spec"
@@ -59,7 +59,7 @@ def wrap_checkify(fn):
     return checkify.checkify(fn, errors=checkify_errors())
 
 
-_COMPILING = re.compile(r"^Compiling ([^\s]+) with global shapes")
+_COMPILING = re.compile(r"^Compiling jit\(([^\s()]+)\) with global shapes")
 
 
 class CompileLog:

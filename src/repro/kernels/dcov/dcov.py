@@ -42,24 +42,25 @@ from repro.kernels.runtime import (  # noqa: F401
 )
 
 
-# VMEM working-set budget for one (block, block, cpad) tile family. The
-# gram pass keeps ~3 such float32 intermediates live (the |xi−xj| tile,
-# the centered tile and the flattened matmul operand), so the block edge
-# is sized to keep 3·b²·cpad·4B within budget — half of a v5e core's
-# ~16 MB VMEM, leaving headroom for the row-sum operands and Mosaic's
-# own buffers.
+# VMEM budget for one (block, block, cpad) float32 intermediate, half of
+# the 16 MiB scoped VMEM limit of a v5e core. Mosaic lays the last dim of
+# that intermediate out on the 128 lanes, so a cpad=8 tile occupies 16×
+# its logical bytes: block 256 asks for ~25 MiB of scoped VMEM and is
+# refused, block 128 (8 MiB lane-padded) compiles.
 _VMEM_BUDGET = 8 << 20
+_LANES = 128
 
 
 def _auto_block(n: int, cpad: int) -> int:
-    """Largest power-of-two block edge whose tile family fits the VMEM
-    budget (never larger than n, never smaller than 8). Callers that
-    pass an explicit ``block`` keep it — this only drives the default,
-    so window/grid sizes beyond one VMEM tile run the real blocked
-    kernel instead of degrading to an oversized single tile."""
-    edge = int((_VMEM_BUDGET / (12 * cpad)) ** 0.5)
+    """Largest power-of-two block edge whose lane-padded (b, b, cpad)
+    float32 tile fits the VMEM budget (never larger than n, never
+    smaller than 8). Callers that pass an explicit ``block`` keep it —
+    this only drives the default, so window/grid sizes beyond one VMEM
+    tile run the real blocked kernel instead of degrading to an
+    oversized single tile."""
+    lane_bytes = pl.cdiv(cpad, _LANES) * _LANES * 4
     block = 8
-    while block * 2 <= min(edge, max(n, 8)) and block < 1024:
+    while block * 2 <= max(n, 8) and (2 * block) ** 2 * lane_bytes <= _VMEM_BUDGET:
         block *= 2
     return block
 

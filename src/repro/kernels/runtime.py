@@ -6,12 +6,14 @@ attention and SSD-scan entry points — and the bench harness view
 ``benchmarks.common.pallas_interpret`` — can never disagree about
 whether the Mosaic compiler or the interpreter runs a kernel.
 
-Resolution order:
+Resolution:
 
-1. ``PALLAS_INTERPRET`` env var, parsed by the repo's single truthy
-   parser (:mod:`repro.envflags`): "0"/"false"/"no" forces compiled
-   mode, any other non-empty value forces interpret mode.
-2. Backend auto-detect: interpret everywhere except a real TPU backend.
+- On a TPU backend the kernels always compile with Mosaic. A
+  ``PALLAS_INTERPRET`` value asking for interpret mode there is an
+  error, never a silent switch to the interpreter.
+- Elsewhere the kernels run in interpret mode. ``PALLAS_INTERPRET``
+  (parsed by :mod:`repro.envflags`) can only confirm that: asking for
+  compiled mode off a TPU is an error too, since Mosaic cannot run there.
 """
 from __future__ import annotations
 
@@ -30,9 +32,15 @@ def parse_interpret_env(raw: Optional[str]) -> Optional[bool]:
 
 
 def default_interpret() -> bool:
-    """Interpret mode unless running on an actual TPU backend; the
-    PALLAS_INTERPRET env flag overrides the backend-derived default."""
+    """Interpret mode exactly when the backend is not a TPU. A
+    PALLAS_INTERPRET value that contradicts the backend raises."""
+    interpret = jax.default_backend() != "tpu"
     env = parse_interpret_env(os.environ.get("PALLAS_INTERPRET"))
-    if env is not None:
-        return env
-    return jax.default_backend() != "tpu"
+    if env is not None and env != interpret:
+        raise RuntimeError(
+            f"PALLAS_INTERPRET={os.environ['PALLAS_INTERPRET']!r} asks for "
+            f"{'interpret' if env else 'compiled'} Pallas kernels on the "
+            f"{jax.default_backend()!r} backend, which runs them "
+            f"{'interpreted' if interpret else 'compiled'}; unset it"
+        )
+    return interpret

@@ -37,22 +37,26 @@ def _ssd_kernel(
 
     x = x_ref[0, 0, 0].astype(jnp.float32)  # (Q, hd)
     dt = dt_ref[0, 0, 0].astype(jnp.float32)  # (Q, 1)
-    A = a_ref[0, 0]  # scalar (negative)
+    A = a_ref[0, pl.program_id(1)]  # this head's scalar (negative), SMEM
     Bm = b_ref[0, 0, 0].astype(jnp.float32)  # (Q, N)
     Cm = c_ref[0, 0, 0].astype(jnp.float32)  # (Q, N)
 
-    dA = dt[:, 0] * A  # (Q,)
-    cs = jnp.cumsum(dA)  # inclusive
+    dA = dt * A  # (Q, 1)
     xdt = x * dt  # (Q, hd)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    # inclusive cumsum of dA as masked reductions (Mosaic lowers no
+    # cumsum): the row form sums down the columns, the column form reads
+    # the row form's diagonal back across the lanes
+    cs_row = jnp.sum(jnp.where(ii <= jj, dA, 0.0), axis=0, keepdims=True)
+    cs = jnp.sum(jnp.where(ii == jj, cs_row, 0.0), axis=1, keepdims=True)
+    total = jnp.sum(dA, axis=0, keepdims=True)  # (1, 1) = cs[-1]
 
     # intra-chunk
     att = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (Q, Q)
-    decay = jnp.exp(cs[:, None] - cs[None, :])
-    ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    L = jnp.where(ii >= jj, decay, 0.0)
+    L = jnp.where(ii >= jj, jnp.exp(cs - cs_row), 0.0)
     y = jax.lax.dot_general(
         att * L, xdt, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -63,13 +67,13 @@ def _ssd_kernel(
     y_off = jax.lax.dot_general(
         Cm, state, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (Q, hd)
-    y = y + jnp.exp(cs)[:, None] * y_off
+    y = y + jnp.exp(cs) * y_off
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
 
     # state update
-    seg = jnp.exp(cs[-1] - cs)  # (Q,)
-    new_state = state * jnp.exp(cs[-1]) + jax.lax.dot_general(
-        xdt, Bm * seg[:, None], (((0,), (0,)), ((), ())),
+    seg = jnp.exp(total - cs)  # (Q, 1)
+    new_state = state * jnp.exp(total) + jax.lax.dot_general(
+        xdt, Bm * seg, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )  # (hd, N)
     state_ref[...] = new_state
@@ -123,7 +127,9 @@ def ssd_pallas(
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, hd), lambda b_, h, c: (b_, h, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, 1), lambda b_, h, c: (b_, h, c, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b_, h, c: (0, h)),
+            # the whole (1, NH) row of A in SMEM: a (1, 1) VMEM block of
+            # it breaks Mosaic's (8, 128) tiling rule
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, 1, chunk, n), lambda b_, h, c: (b_, 0, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, n), lambda b_, h, c: (b_, 0, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, hd, n), lambda b_, h, c: (b_, h, 0, 0, 0)),

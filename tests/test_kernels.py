@@ -130,3 +130,32 @@ def test_ssd_decode_step_consistent_with_scan():
         ys.append(jnp.einsum("bn,bhpn->bhp", Cm[:, t], state))
     y_step = jnp.stack(ys, axis=1)
     np.testing.assert_allclose(np.asarray(y_scan), np.asarray(y_step), atol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "n,cpad,block",
+    [
+        (5, 8, 8),
+        (100, 8, 64),
+        (2048, 8, 128),  # 256 would need ~25 MiB of a v5e's 16 MiB VMEM
+        (2048, 136, 64),  # 136 columns pad to 256 lanes
+    ],
+)
+def test_dcov_auto_block_counts_lane_padding(n, cpad, block):
+    from repro.kernels.dcov.dcov import _auto_block
+
+    assert _auto_block(n, cpad) == block
+
+
+def test_interpret_mode_follows_the_backend(monkeypatch):
+    """Off a TPU the kernels interpret; an env value that asks for the
+    other mode is an error, never a silent switch."""
+    from repro.kernels.runtime import default_interpret
+
+    monkeypatch.delenv("PALLAS_INTERPRET", raising=False)
+    assert default_interpret() is True
+    monkeypatch.setenv("PALLAS_INTERPRET", "1")
+    assert default_interpret() is True
+    monkeypatch.setenv("PALLAS_INTERPRET", "0")
+    with pytest.raises(RuntimeError, match="PALLAS_INTERPRET"):
+        default_interpret()

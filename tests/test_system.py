@@ -71,8 +71,9 @@ def test_train_launcher_runs():
 def test_serve_launcher_runs():
     from repro.launch.serve import serve
 
-    m = serve("qwen2.5-3b", requests=2, prompt_len=8, new_tokens=4, batch=2)
+    m, rt = serve("qwen2.5-3b", requests=2, prompt_len=8, new_tokens=4, batch=2)
     assert m["requests"] == 2
+    assert sorted(r.output.size for r in rt.done) == [4, 4]
 
 
 def test_input_specs_cover_all_pairs():
