@@ -12,6 +12,11 @@ Three entry points per architecture:
   forward_train(ctx, params, batch)             -> (logits, aux)
   prefill(ctx, params, batch)                   -> (cache, last_logits)
   decode_step(ctx, params, cache, tokens)       -> (cache, logits)
+
+``prefill`` and ``decode_step`` name their work with ``jax.named_scope``,
+so a device trace can attribute each operation: ``embed``, ``attn`` (QKV,
+rope, attention, output projection), ``kv_write`` (every cache write),
+``mlp`` and ``head`` (final norm and unembed).
 """
 from __future__ import annotations
 
@@ -312,17 +317,23 @@ def layer_full(
             cache["conv"] = _conv_tail(cfg, hn, lp["ssm"])
         return h + out, cache, aux
     if cfg.mla is not None:
-        attn_out, (latent, krope) = mla_full(cfg, lp["attn"], hn, positions, ctx.rcfg)
+        with jax.named_scope("attn"):
+            attn_out, (latent, krope) = mla_full(
+                cfg, lp["attn"], hn, positions, ctx.rcfg
+            )
         if want_cache:
-            cache["ckv"] = latent.astype(jnp.bfloat16)
-            cache["krope"] = krope.astype(jnp.bfloat16)
+            with jax.named_scope("kv_write"):
+                cache["ckv"] = latent.astype(jnp.bfloat16)
+                cache["krope"] = krope.astype(jnp.bfloat16)
     else:
-        attn_out, (k, v) = attn_full(
-            ctx, lp["attn"], hn, positions, pos3, window, causal=True
-        )
+        with jax.named_scope("attn"):
+            attn_out, (k, v) = attn_full(
+                ctx, lp["attn"], hn, positions, pos3, window, causal=True
+            )
         if want_cache:
-            cache["k"] = k.astype(jnp.bfloat16)
-            cache["v"] = v.astype(jnp.bfloat16)
+            with jax.named_scope("kv_write"):
+                cache["k"] = k.astype(jnp.bfloat16)
+                cache["v"] = v.astype(jnp.bfloat16)
     if cfg.arch_type == "hybrid":
         ssm_out, state = ssm_lib.mamba2_forward(cfg, lp["ssm"], hn, ctx.rcfg)
         g = jax.nn.sigmoid(lp["mix_gate"].astype(jnp.float32))
@@ -339,16 +350,23 @@ def layer_full(
             cache["cross_k"] = ck.astype(jnp.bfloat16)
             cache["cross_v"] = cv.astype(jnp.bfloat16)
     hn2 = _norm(cfg, lp, "ln2", h)
-    fp = lp["ffn"]
+    with jax.named_scope("mlp"):
+        ff, aux = _ffn(ctx, lp["ffn"], hn2)
+    return h + ff, cache, aux
+
+
+def _ffn(ctx: ApplyCtx, fp: dict, hn2: jax.Array):
+    """The layer's feed-forward block: (output, MoE auxiliary loss)."""
+    cfg = ctx.cfg
     if "router" in fp:
-        ff, aux = moe_lib.moe_ffn(cfg, ctx.rcfg, ctx.mesh, fp, hn2)
-    elif cfg.arch_type == "audio":
+        return moe_lib.moe_ffn(cfg, ctx.rcfg, ctx.mesh, fp, hn2)
+    if cfg.arch_type == "audio":
         from repro.models.layers import gelu_mlp
 
         ff = gelu_mlp(hn2, fp["wg"], fp["bg"], fp["wd"], fp["bd"])
     else:
         ff = swiglu(hn2, fp["wg"], fp["wu"], fp["wd"])
-    return h + ff, cache, aux
+    return ff, jnp.zeros((), jnp.float32)
 
 
 def _conv_tail(cfg: ModelConfig, hn: jax.Array, sp: dict) -> jax.Array:
@@ -408,7 +426,10 @@ def run_stack(
         (h, aux), cache = jax.lax.scan(body, (h, aux), seg_params)
         seg_caches.append(cache)
     if want_cache and seg_caches:
-        caches = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *seg_caches)
+        with jax.named_scope("kv_write"):
+            caches = jax.tree.map(
+                lambda *xs: jnp.concatenate(xs, axis=0), *seg_caches
+            )
     else:
         caches = seg_caches[0] if seg_caches else {}
     return h, aux, caches
@@ -427,7 +448,8 @@ def run_prologue(ctx, pro_params, windows, h, positions, pos3, want_cache):
         caches.append(cache)
         aux = aux + aux_l
     if want_cache and caches:
-        caches = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
+        with jax.named_scope("kv_write"):
+            caches = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
     else:
         caches = {}
     return h, aux, caches
@@ -566,7 +588,8 @@ def prefill(ctx: ApplyCtx, params, batch, capacity: Optional[int] = None):
     cache: Dict[str, Any] = {}
     if cfg.is_encoder_decoder:
         enc_out, enc_pos = encode(ctx, params, batch["enc_feats"])
-    h = embed(ctx, params, tokens, positions, batch.get("vision_embeds"))
+    with jax.named_scope("embed"):
+        h = embed(ctx, params, tokens, positions, batch.get("vision_embeds"))
     n_pro = _n_prologue(cfg)
     if n_pro:
         h, _, c_pro = run_prologue(
@@ -590,9 +613,11 @@ def prefill(ctx: ApplyCtx, params, batch, capacity: Optional[int] = None):
                 return jnp.pad(leaf, width)
             return leaf
 
-        cache = jax.tree_util.tree_map_with_path(pad_seq, cache)
+        with jax.named_scope("kv_write"):
+            cache = jax.tree_util.tree_map_with_path(pad_seq, cache)
     cache["length"] = jnp.asarray(s, jnp.int32)
-    logits = unembed(ctx, params, h[:, -1:])
+    with jax.named_scope("head"):
+        logits = unembed(ctx, params, h[:, -1:])
     return cache, logits
 
 
@@ -670,39 +695,45 @@ def layer_decode(ctx: ApplyCtx, lp, window, lcache, h, pos, pos3):
     if cfg.mla is not None:
         from repro.models.mla import _latent  # shared projection helper
 
-        latent, krope = _latent(cfg, lp["attn"], hn, pos)
+        with jax.named_scope("attn"):
+            latent, krope = _latent(cfg, lp["attn"], hn, pos)
         w = lcache["ckv"].shape[1]
         slot = t % w
-        ckv = jax.lax.dynamic_update_slice(
-            lcache["ckv"], latent.astype(lcache["ckv"].dtype), (0, slot, 0)
-        )
-        krc = jax.lax.dynamic_update_slice(
-            lcache["krope"], krope.astype(lcache["krope"].dtype), (0, slot, 0)
-        )
-        kv_pos = jnp.broadcast_to(_ring_kv_pos(t, w), (b, w))
-        attn_out = mla_decode(cfg, lp["attn"], hn, pos, ckv.astype(hn.dtype),
-                              krc.astype(hn.dtype), kv_pos)
+        with jax.named_scope("kv_write"):
+            ckv = jax.lax.dynamic_update_slice(
+                lcache["ckv"], latent.astype(lcache["ckv"].dtype), (0, slot, 0)
+            )
+            krc = jax.lax.dynamic_update_slice(
+                lcache["krope"], krope.astype(lcache["krope"].dtype), (0, slot, 0)
+            )
+        with jax.named_scope("attn"):
+            kv_pos = jnp.broadcast_to(_ring_kv_pos(t, w), (b, w))
+            attn_out = mla_decode(cfg, lp["attn"], hn, pos, ckv.astype(hn.dtype),
+                                  krc.astype(hn.dtype), kv_pos)
         new_cache["ckv"], new_cache["krope"] = ckv, krc
     else:
-        q, k, v = _qkv(cfg, lp["attn"], hn, hn)
-        q, k = _rope_qk(cfg, q, k, pos, pos3)
+        with jax.named_scope("attn"):
+            q, k, v = _qkv(cfg, lp["attn"], hn, hn)
+            q, k = _rope_qk(cfg, q, k, pos, pos3)
         w = lcache["k"].shape[1]
         slot = t % w
-        kc = jax.lax.dynamic_update_slice(
-            lcache["k"], k.astype(lcache["k"].dtype), (0, slot, 0, 0)
-        )
-        vc = jax.lax.dynamic_update_slice(
-            lcache["v"], v.astype(lcache["v"].dtype), (0, slot, 0, 0)
-        )
-        kv_pos = jnp.broadcast_to(_ring_kv_pos(t, w), (b, w))
-        attn_out = attention(
-            q, kc.astype(hn.dtype), vc.astype(hn.dtype), pos, kv_pos,
-            causal=True, window=window, rcfg=ctx.rcfg,
-        )
-        attn_out = attn_out.reshape(b, 1, -1)
-        attn_out = jnp.einsum(
-            "bse,ed->bsd", attn_out, lp["attn"]["wo"].astype(hn.dtype)
-        )
+        with jax.named_scope("kv_write"):
+            kc = jax.lax.dynamic_update_slice(
+                lcache["k"], k.astype(lcache["k"].dtype), (0, slot, 0, 0)
+            )
+            vc = jax.lax.dynamic_update_slice(
+                lcache["v"], v.astype(lcache["v"].dtype), (0, slot, 0, 0)
+            )
+        with jax.named_scope("attn"):
+            kv_pos = jnp.broadcast_to(_ring_kv_pos(t, w), (b, w))
+            attn_out = attention(
+                q, kc.astype(hn.dtype), vc.astype(hn.dtype), pos, kv_pos,
+                causal=True, window=window, rcfg=ctx.rcfg,
+            )
+            attn_out = attn_out.reshape(b, 1, -1)
+            attn_out = jnp.einsum(
+                "bse,ed->bsd", attn_out, lp["attn"]["wo"].astype(hn.dtype)
+            )
         new_cache["k"], new_cache["v"] = kc, vc
 
     if cfg.arch_type == "hybrid":
@@ -730,15 +761,8 @@ def layer_decode(ctx: ApplyCtx, lp, window, lcache, h, pos, pos3):
         h = h + jnp.einsum("bse,ed->bsd", out, lp["cross"]["wo"].astype(hn.dtype))
 
     hn2 = _norm(cfg, lp, "ln2", h)
-    fp = lp["ffn"]
-    if "router" in fp:
-        ff, _ = moe_lib.moe_ffn(cfg, ctx.rcfg, ctx.mesh, fp, hn2)
-    elif cfg.arch_type == "audio":
-        from repro.models.layers import gelu_mlp
-
-        ff = gelu_mlp(hn2, fp["wg"], fp["bg"], fp["wd"], fp["bd"])
-    else:
-        ff = swiglu(hn2, fp["wg"], fp["wu"], fp["wd"])
+    with jax.named_scope("mlp"):
+        ff, _ = _ffn(ctx, lp["ffn"], hn2)
     return h + ff, new_cache
 
 
@@ -756,7 +780,8 @@ def decode_step(ctx: ApplyCtx, params, cache, tokens):
         if cfg.rope_type == "mrope"
         else None
     )
-    h = embed(ctx, params, tokens, pos, None)
+    with jax.named_scope("embed"):
+        h = embed(ctx, params, tokens, pos, None)
     n_pro = _n_prologue(cfg)
     new_cache = dict(cache)
     if n_pro:
@@ -767,7 +792,8 @@ def decode_step(ctx: ApplyCtx, params, cache, tokens):
             lc = jax.tree.map(lambda a: a[i], cache["pro"])
             h, lc = layer_decode(ctx, lp, windows[i], lc, h, pos, pos3)
             pro_caches.append(lc)
-        new_cache["pro"] = jax.tree.map(lambda *xs: jnp.stack(xs), *pro_caches)
+        with jax.named_scope("kv_write"):
+            new_cache["pro"] = jax.tree.map(lambda *xs: jnp.stack(xs), *pro_caches)
 
     windows = layer_windows(cfg, cfg.n_layers - n_pro, n_pro)
     seg_caches = []
@@ -783,11 +809,13 @@ def decode_step(ctx: ApplyCtx, params, cache, tokens):
 
         h, seg_out = jax.lax.scan(body, h, (seg_params, seg_cache))
         seg_caches.append(seg_out)
-    new_cache["main"] = jax.tree.map(
-        lambda *xs: jnp.concatenate(xs, axis=0), *seg_caches
-    )
+    with jax.named_scope("kv_write"):
+        new_cache["main"] = jax.tree.map(
+            lambda *xs: jnp.concatenate(xs, axis=0), *seg_caches
+        )
     new_cache["length"] = t + 1
-    logits = unembed(ctx, params, h)
+    with jax.named_scope("head"):
+        logits = unembed(ctx, params, h)
     return new_cache, logits
 
 

@@ -48,6 +48,10 @@ its *own* engine (a different registry model) — and
 ``set_slot_allocation`` is the live per-tenant slot knob the joint
 CORAL config drives.
 
+Every boundary of a ring pass (admission, prefill and decode dispatch,
+and the retire's wait, copy, sampling and bookkeeping) opens a host span
+on the ``jax.profiler`` trace's clock (``repro.serving.spans``).
+
 Groups are formed from same-prompt-length requests only (no padding to a
 neighbour's length), which fixes the old scheduler's silent truncation of
 prompts longer than the group head's.
@@ -59,7 +63,10 @@ import dataclasses
 import time
 from typing import Deque, Dict, List, Mapping, Optional, Tuple
 
+import jax
 import numpy as np
+
+from repro.serving import spans
 
 # The single-tenant compatibility ring every runtime starts with.
 DEFAULT_TENANT = "default"
@@ -73,6 +80,7 @@ class Request:
     arrival_s: Optional[float] = None  # offset from clock start; None = now
     arrived: float = dataclasses.field(default_factory=time.monotonic)
     started: float = 0.0  # prefill dispatch time
+    first_token: float = 0.0  # retire that put the first token on the host
     finished: float = 0.0
     tokens: List[int] = dataclasses.field(default_factory=list)
     output: Optional[np.ndarray] = None
@@ -86,10 +94,11 @@ class Request:
 class _Slot:
     """One in-flight decode group: KV cache + outstanding logits future."""
 
-    __slots__ = ("group", "cache", "logits", "live", "remaining")
+    __slots__ = ("group", "gid", "cache", "logits", "live", "remaining")
 
     def __init__(self):
         self.group: Optional[List[Request]] = None
+        self.gid = 0  # the ring's count of groups formed, this one included
         self.cache = None
         self.logits = None
         self.live: List[bool] = []
@@ -154,50 +163,61 @@ class _TenantRing:
 
     # ---------------------------------------------------------- pipeline
     def _start_group(self, slot: _Slot, group: List[Request]) -> None:
-        prompts = np.stack([r.prompt for r in group])
-        if len(group) < self.batch:
-            prompts = np.pad(prompts, ((0, self.batch - len(group)), (0, 0)))
-        t = time.monotonic()
-        for r in group:
-            r.started = t
-        # async dispatch: the prefill (and its first logits) queue behind
-        # whatever the other slots — every tenant's — already have in
-        # flight. The last-position slice is dispatched here, not at
-        # retire: retire must only ever *transfer* a ready buffer — a
-        # sliced read there would enqueue a fresh device op behind every
-        # other slot's in-flight decode and serialize the whole ring.
-        slot.cache, logits = self.engine.prefill(prompts)
-        slot.logits = logits[:, -1:]
+        self.prefills += 1
+        slot.gid = self.prefills
+        with spans.span(spans.PREFILL_DISPATCH, group=slot.gid):
+            prompts = np.stack([r.prompt for r in group])
+            if len(group) < self.batch:
+                prompts = np.pad(prompts, ((0, self.batch - len(group)), (0, 0)))
+            t = time.monotonic()
+            for r in group:
+                r.started = t
+            # async dispatch: the prefill (and its first logits) queue behind
+            # whatever the other slots — every tenant's — already have in
+            # flight. The last-position slice is dispatched here, not at
+            # retire: retire must only ever *transfer* a ready buffer — a
+            # sliced read there would enqueue a fresh device op behind every
+            # other slot's in-flight decode and serialize the whole ring.
+            slot.cache, logits = self.engine.prefill(prompts)
+            slot.logits = logits[:, -1:]
         slot.group = group
         slot.live = [True] * len(group)
         slot.remaining = [max(1, int(r.max_new_tokens)) for r in group]
-        self.prefills += 1
 
     def _retire(self, slot: _Slot) -> None:
         """Host stage: block on this slot's logits, sample greedily on the
         host, account tokens/completions, then dispatch the next decode."""
-        # (B, 1, vocab) device→host copy: blocks on *this slot's* buffer
-        # only (a pure transfer skips the execute queue, so the other
-        # slots' decodes keep running underneath the host work)
-        lg = np.asarray(slot.logits)
-        tok = lg[:, -1].argmax(axis=-1).astype(np.int32)  # host-side sampling
-        t = time.monotonic()
-        n_live = 0
-        for j, r in enumerate(slot.group):
-            if not slot.live[j]:
-                continue
-            r.tokens.append(int(tok[j]))
-            slot.remaining[j] -= 1
-            n_live += 1
-            if slot.remaining[j] == 0:
-                slot.live[j] = False
-                r.finished = t
-                r.output = np.asarray(r.tokens, np.int32)
-                self.done.append(r)
-        self._record(t, n_live)
-        self.steps += 1
+        gid = slot.gid
+        # waits on *this slot's* buffer only: the other slots' decodes keep
+        # running underneath the host work that follows
+        with spans.span(spans.RETIRE_WAIT, group=gid):
+            jax.block_until_ready(slot.logits)
+        # (B, 1, vocab) device→host copy of a ready buffer: a pure transfer
+        with spans.span(spans.RETIRE_COPY, group=gid):
+            lg = np.asarray(slot.logits)
+        with spans.span(spans.RETIRE_SAMPLE, group=gid):
+            tok = lg[:, -1].argmax(axis=-1).astype(np.int32)  # host-side sampling
+        with spans.span(spans.RETIRE_BOOK, group=gid):
+            t = time.monotonic()
+            n_live = 0
+            for j, r in enumerate(slot.group):
+                if not slot.live[j]:
+                    continue
+                if not r.tokens:
+                    r.first_token = t
+                r.tokens.append(int(tok[j]))
+                slot.remaining[j] -= 1
+                n_live += 1
+                if slot.remaining[j] == 0:
+                    slot.live[j] = False
+                    r.finished = t
+                    r.output = np.asarray(r.tokens, np.int32)
+                    self.done.append(r)
+            self._record(t, n_live)
+            self.steps += 1
         if any(slot.live):
-            slot.cache, slot.logits = self.engine.decode(slot.cache, tok[:, None])
+            with spans.span(spans.DECODE_DISPATCH, group=gid):
+                slot.cache, slot.logits = self.engine.decode(slot.cache, tok[:, None])
         else:
             slot.group = None
             slot.cache = slot.logits = None
@@ -214,7 +234,8 @@ class _TenantRing:
             self.slots.append(_Slot())
         for slot in self.slots:
             if slot.group is None:
-                group = self._form_group()
+                with spans.span(spans.ADMIT):
+                    group = self._form_group()
                 if group:
                     self._start_group(slot, group)
                     progressed = True

@@ -307,3 +307,77 @@ def test_closed_loop_coral_finds_feasible_under_bursty_trace(engine):
     # the knob was genuinely applied: the runtime ran at the proposed
     # concurrency levels, not a fixed one
     assert len({int(r.config[-1]) for r in records}) > 1
+
+
+RETIRE_SPANS = ("serve.retire.wait", "serve.retire.copy", "serve.retire.sample",
+                "serve.retire.book")
+
+
+def _serve_with_one_pod_request(engine):
+    """Six local requests (varied lengths, so groups end at different
+    passes) and one shipped to the pod, drained."""
+    from repro.device.network import get_network
+
+    rt = ServingRuntime(engine, concurrency=2)
+    rt.attach_pod(get_network("lte-uplink"), pod_time_per_token=1e-3)
+    rt.set_offload(0.15)  # the seventh admitted request tips the route past 1
+    for rid in range(7):
+        rt.submit(_req(rid, 8, n=2 + rid % 3, seed=rid))
+    rt.drain()
+    return rt
+
+
+def _program_spans(trace_dir):
+    """(name, start, group argument) of the runtime's host spans, in order."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)[0]
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                name = e.name.split("#", 1)[0]
+                if name.startswith("serve."):
+                    group = next((v for k, v in e.stats if k == "group"), None)
+                    spans.append((name, e.start_ns, group))
+    return sorted(spans, key=lambda s: s[1])
+
+
+def test_spans_and_first_token_stamps_under_a_trace(engine, tmp_path):
+    with jax.profiler.trace(str(tmp_path)):
+        rt = _serve_with_one_pod_request(engine)
+    plain = _serve_with_one_pod_request(engine)
+    # tracing changes nothing that is served
+    served = {r.rid: r.output.tolist() for r in rt.done}
+    assert served == {r.rid: r.output.tolist() for r in plain.done}
+    assert len(served) == 7
+
+    pod = [r for r in rt.done if r.route == "pod"]
+    local = [r for r in rt.done if r.route != "pod"]
+    assert len(pod) == 1 and pod[0].first_token == 0.0
+    assert local and all(r.started <= r.first_token <= r.finished for r in local)
+
+    spans = _program_spans(tmp_path)
+    names = [s[0] for s in spans]
+    assert names.count("serve.prefill.dispatch") == rt.prefills == 3
+    assert names.count("serve.admit") >= rt.prefills
+    # every retire: wait, copy, sample, book, then the group's next decode
+    # unless the group ended
+    retires = [s for s in spans if s[0] != "serve.admit"
+               and s[0] != "serve.prefill.dispatch"]
+    seen, i = 0, 0
+    while i < len(retires):
+        group = retires[i:i + 4]
+        assert tuple(s[0] for s in group) == RETIRE_SPANS
+        assert len({s[2] for s in group}) == 1
+        i += 4
+        if i < len(retires) and retires[i][0] == "serve.decode.dispatch":
+            assert retires[i][2] == group[0][2]
+            i += 1
+        seen += 1
+    assert seen == rt.steps
+    assert names.count("serve.decode.dispatch") == rt.steps - rt.prefills
+    # one group id per group formed
+    assert {s[2] for s in spans if s[0] == "serve.prefill.dispatch"} == {1, 2, 3}
