@@ -17,6 +17,14 @@ Three entry points per architecture:
 so a device trace can attribute each operation: ``embed``, ``attn`` (QKV,
 rope, attention, output projection), ``kv_write`` (every cache write),
 ``mlp`` and ``head`` (final norm and unembed).
+
+The cache stacks each leaf over its layers. Attention K/V are stored
+``(L, B, kv_heads, W, head_dim)``, the order decode attention reads, so a
+layer's slab is a plain slice the attention fusion reads in place; MLA's
+latent ``ckv``/``krope`` are ``(L, B, W, r)``. ``decode_step`` carries the
+stacked leaves through the layer scan and updates them in place: the new
+token is written at its ring slot, SSM/conv state is replaced, and each
+layer reads its slab from the updated carry.
 """
 from __future__ import annotations
 
@@ -25,6 +33,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import ModelConfig
 from repro.configs.runtime import RunConfig
@@ -45,6 +54,21 @@ from repro.models.layers import (
 from repro.models.mla import mla_decode, mla_full, mla_param_specs
 
 BIG_WINDOW = 1 << 30
+
+# Ring-slot (sequence) axis of each stacked cache leaf that holds one entry
+# per position: K/V (L, B, kv, W, hd); MLA's latent (L, B, W, r) has no
+# head axis.
+SLOT_AXIS = {"k": 3, "v": 3, "ckv": 2, "krope": 2}
+
+
+def _to_cache(name: str, x: jax.Array) -> jax.Array:
+    """A layer's (B, S, ...) entries of ring leaf ``name`` -> stored order."""
+    return jnp.moveaxis(x, 1, SLOT_AXIS[name] - 1)
+
+
+def _from_cache(name: str, x: jax.Array) -> jax.Array:
+    """A layer's slab of ring leaf ``name`` -> (B, S, ...)."""
+    return jnp.moveaxis(x, SLOT_AXIS[name] - 1, 1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -323,8 +347,8 @@ def layer_full(
             )
         if want_cache:
             with jax.named_scope("kv_write"):
-                cache["ckv"] = latent.astype(jnp.bfloat16)
-                cache["krope"] = krope.astype(jnp.bfloat16)
+                cache["ckv"] = _to_cache("ckv", latent.astype(jnp.bfloat16))
+                cache["krope"] = _to_cache("krope", krope.astype(jnp.bfloat16))
     else:
         with jax.named_scope("attn"):
             attn_out, (k, v) = attn_full(
@@ -332,8 +356,8 @@ def layer_full(
             )
         if want_cache:
             with jax.named_scope("kv_write"):
-                cache["k"] = k.astype(jnp.bfloat16)
-                cache["v"] = v.astype(jnp.bfloat16)
+                cache["k"] = _to_cache("k", k.astype(jnp.bfloat16))
+                cache["v"] = _to_cache("v", v.astype(jnp.bfloat16))
     if cfg.arch_type == "hybrid":
         ssm_out, state = ssm_lib.mamba2_forward(cfg, lp["ssm"], hn, ctx.rcfg)
         g = jax.nn.sigmoid(lp["mix_gate"].astype(jnp.float32))
@@ -607,9 +631,9 @@ def prefill(ctx: ApplyCtx, params, batch, capacity: Optional[int] = None):
 
         def pad_seq(path, leaf):
             name = str(path[-1].key) if hasattr(path[-1], "key") else ""
-            if name in ("k", "v", "ckv", "krope"):
+            if name in SLOT_AXIS:
                 width = [(0, 0)] * leaf.ndim
-                width[2] = (0, pad)  # (L, B, W, ...) — grow the slot axis
+                width[SLOT_AXIS[name]] = (0, pad)  # grow the ring
                 return jnp.pad(leaf, width)
             return leaf
 
@@ -640,7 +664,12 @@ def _ring_kv_pos(length: jax.Array, w: int) -> jax.Array:
 def abstract_cache(
     cfg: ModelConfig, batch: int, w: int, enc_len: Optional[int] = None
 ) -> dict:
-    """ShapeDtypeStruct cache pytree (capacity ``w`` per attention layer)."""
+    """ShapeDtypeStruct cache pytree (capacity ``w`` per attention layer).
+
+    Each leaf stacks its layers first: K/V ``(L, B, kv_heads, w,
+    head_dim)``, MLA latent ``(L, B, w, r)``, SSM state ``(L, B, heads,
+    headdim, d_state)``, conv tail ``(L, B, d_conv - 1, conv_dim)``, cross
+    K/V ``(L, B, enc_len, kv_heads, head_dim)``; ``length`` is a scalar."""
 
     def sds(shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
@@ -653,8 +682,8 @@ def abstract_cache(
                 c["ckv"] = sds((n, batch, w, cfg.mla.kv_lora_rank))
                 c["krope"] = sds((n, batch, w, cfg.mla.qk_rope_head_dim))
             else:
-                c["k"] = sds((n, batch, w, hkv, hd))
-                c["v"] = sds((n, batch, w, hkv, hd))
+                c["k"] = sds((n, batch, hkv, w, hd))
+                c["v"] = sds((n, batch, hkv, w, hd))
         if cfg.arch_type in ("ssm", "hybrid"):
             s = cfg.ssm
             nh = s.n_ssm_heads(cfg.d_model)
@@ -675,83 +704,102 @@ def abstract_cache(
     return cache
 
 
-def layer_decode(ctx: ApplyCtx, lp, window, lcache, h, pos, pos3):
-    """One-token decode through one layer. Returns (h, updated lcache)."""
+def _layer_slab(cache: dict, name: str, i) -> jax.Array:
+    """Layer ``i`` of the stacked leaf ``cache[name]``, as (B, ...)."""
+    slab = jax.lax.dynamic_index_in_dim(cache[name], i, 0, keepdims=False)
+    return _from_cache(name, slab) if name in SLOT_AXIS else slab
+
+
+def _write(cache: dict, name: str, i, value: jax.Array, slot=None) -> None:
+    """Write ``value`` (B, ...) into layer ``i`` of the stacked leaf
+    ``cache[name]``: one token at ring ``slot`` of a ring leaf or, with no
+    slot, the layer's whole state. The leaf is a loop carry, so XLA
+    updates it in place. The carry is held to the caller's row-major
+    layout: left free, the TPU compiler lays a ring out slot-major for the
+    token write and then copies the whole cache in and out of the loop."""
+    leaf = cache[name]
+    start = [i] + [0] * (leaf.ndim - 1)
+    if slot is not None:
+        value, start[SLOT_AXIS[name]] = _to_cache(name, value), slot
+    new = jax.lax.dynamic_update_slice(leaf, value[None].astype(leaf.dtype), start)
+    cache[name] = with_layout_constraint(
+        new, Layout(major_to_minor=tuple(range(new.ndim)))
+    )
+
+
+def layer_decode(ctx: ApplyCtx, lp, window, cache, i, h, pos, pos3):
+    """One-token decode through layer ``i`` of a stack.
+
+    ``cache`` holds the stack's leaves, stacked over its layers as
+    ``abstract_cache`` lays them out. The token's K/V (or latent) is
+    written at its ring slot and the SSM/conv state replaced; attention
+    then reads the layer's slab from the updated leaf. Returns (h, cache)."""
     cfg = ctx.cfg
     b = h.shape[0]
     t = pos[0, 0]  # scalar position (batch-aligned serving)
     hn = _norm(cfg, lp, "ln1", h)
-    new_cache = dict(lcache)
+    cache = dict(cache)
+
+    def ssm_decode():
+        out, st, cv = ssm_lib.mamba2_decode(
+            cfg, lp["ssm"], hn, _layer_slab(cache, "ssm", i).astype(jnp.float32),
+            _layer_slab(cache, "conv", i).astype(hn.dtype),
+        )
+        _write(cache, "ssm", i, st)
+        _write(cache, "conv", i, cv)
+        return out
 
     if cfg.arch_type == "ssm":
-        out, st, cv = ssm_lib.mamba2_decode(
-            cfg, lp["ssm"], hn, lcache["ssm"].astype(jnp.float32),
-            lcache["conv"].astype(hn.dtype),
-        )
-        new_cache["ssm"] = st.astype(lcache["ssm"].dtype)
-        new_cache["conv"] = cv.astype(lcache["conv"].dtype)
-        return h + out, new_cache
+        return h + ssm_decode(), cache
 
     if cfg.mla is not None:
         from repro.models.mla import _latent  # shared projection helper
 
         with jax.named_scope("attn"):
             latent, krope = _latent(cfg, lp["attn"], hn, pos)
-        w = lcache["ckv"].shape[1]
+        w = cache["ckv"].shape[SLOT_AXIS["ckv"]]
         slot = t % w
         with jax.named_scope("kv_write"):
-            ckv = jax.lax.dynamic_update_slice(
-                lcache["ckv"], latent.astype(lcache["ckv"].dtype), (0, slot, 0)
-            )
-            krc = jax.lax.dynamic_update_slice(
-                lcache["krope"], krope.astype(lcache["krope"].dtype), (0, slot, 0)
-            )
+            _write(cache, "ckv", i, latent, slot)
+            _write(cache, "krope", i, krope, slot)
         with jax.named_scope("attn"):
             kv_pos = jnp.broadcast_to(_ring_kv_pos(t, w), (b, w))
-            attn_out = mla_decode(cfg, lp["attn"], hn, pos, ckv.astype(hn.dtype),
-                                  krc.astype(hn.dtype), kv_pos)
-        new_cache["ckv"], new_cache["krope"] = ckv, krc
+            attn_out = mla_decode(
+                cfg, lp["attn"], hn, pos,
+                _layer_slab(cache, "ckv", i).astype(hn.dtype),
+                _layer_slab(cache, "krope", i).astype(hn.dtype), kv_pos,
+            )
     else:
         with jax.named_scope("attn"):
             q, k, v = _qkv(cfg, lp["attn"], hn, hn)
             q, k = _rope_qk(cfg, q, k, pos, pos3)
-        w = lcache["k"].shape[1]
+        w = cache["k"].shape[SLOT_AXIS["k"]]
         slot = t % w
         with jax.named_scope("kv_write"):
-            kc = jax.lax.dynamic_update_slice(
-                lcache["k"], k.astype(lcache["k"].dtype), (0, slot, 0, 0)
-            )
-            vc = jax.lax.dynamic_update_slice(
-                lcache["v"], v.astype(lcache["v"].dtype), (0, slot, 0, 0)
-            )
+            _write(cache, "k", i, k, slot)
+            _write(cache, "v", i, v, slot)
         with jax.named_scope("attn"):
             kv_pos = jnp.broadcast_to(_ring_kv_pos(t, w), (b, w))
             attn_out = attention(
-                q, kc.astype(hn.dtype), vc.astype(hn.dtype), pos, kv_pos,
+                q, _layer_slab(cache, "k", i).astype(hn.dtype),
+                _layer_slab(cache, "v", i).astype(hn.dtype), pos, kv_pos,
                 causal=True, window=window, rcfg=ctx.rcfg,
             )
             attn_out = attn_out.reshape(b, 1, -1)
             attn_out = jnp.einsum(
                 "bse,ed->bsd", attn_out, lp["attn"]["wo"].astype(hn.dtype)
             )
-        new_cache["k"], new_cache["v"] = kc, vc
 
     if cfg.arch_type == "hybrid":
-        ssm_out, st, cv = ssm_lib.mamba2_decode(
-            cfg, lp["ssm"], hn, lcache["ssm"].astype(jnp.float32),
-            lcache["conv"].astype(hn.dtype),
-        )
         g = jax.nn.sigmoid(lp["mix_gate"].astype(jnp.float32))
-        attn_out = (g[0] * attn_out + g[1] * ssm_out).astype(hn.dtype)
-        new_cache["ssm"] = st.astype(lcache["ssm"].dtype)
-        new_cache["conv"] = cv.astype(lcache["conv"].dtype)
+        attn_out = (g[0] * attn_out + g[1] * ssm_decode()).astype(hn.dtype)
 
     h = h + attn_out
 
     if cfg.is_encoder_decoder:
         hc = _norm(cfg, lp, "ln_cross", h)
-        ck = lcache["cross_k"].astype(hn.dtype)
-        cv_ = lcache["cross_v"].astype(hn.dtype)
+        ck = _layer_slab(cache, "cross_k", i).astype(hn.dtype)
+        cv_ = _layer_slab(cache, "cross_v", i).astype(hn.dtype)
         el = ck.shape[1]
         q, _, _ = _qkv(cfg, lp["cross"], hc, hc)
         enc_pos = jnp.broadcast_to(jnp.arange(el, dtype=jnp.int32), (b, el))
@@ -763,11 +811,14 @@ def layer_decode(ctx: ApplyCtx, lp, window, lcache, h, pos, pos3):
     hn2 = _norm(cfg, lp, "ln2", h)
     with jax.named_scope("mlp"):
         ff, _ = _ffn(ctx, lp["ffn"], hn2)
-    return h + ff, new_cache
+    return h + ff, cache
 
 
 def decode_step(ctx: ApplyCtx, params, cache, tokens):
-    """One decode step: tokens (B,1) + cache -> (new cache, logits (B,1,V))."""
+    """One decode step: tokens (B,1) + cache -> (new cache, logits (B,1,V)).
+
+    The stacked cache leaves ride the layer scan as its carry, so a jit
+    that donates them updates the cache in place."""
     cfg = ctx.cfg
     b = tokens.shape[0]
     t = cache["length"]
@@ -786,33 +837,26 @@ def decode_step(ctx: ApplyCtx, params, cache, tokens):
     new_cache = dict(cache)
     if n_pro:
         windows = layer_windows(cfg, n_pro)
-        pro_caches = []
         for i in range(n_pro):
             lp = jax.tree.map(lambda a: a[i], params["prologue"])
-            lc = jax.tree.map(lambda a: a[i], cache["pro"])
-            h, lc = layer_decode(ctx, lp, windows[i], lc, h, pos, pos3)
-            pro_caches.append(lc)
-        with jax.named_scope("kv_write"):
-            new_cache["pro"] = jax.tree.map(lambda *xs: jnp.stack(xs), *pro_caches)
+            h, new_cache["pro"] = layer_decode(
+                ctx, lp, windows[i], new_cache["pro"], i, h, pos, pos3
+            )
 
     windows = layer_windows(cfg, cfg.n_layers - n_pro, n_pro)
-    seg_caches = []
+    stack = cache["main"]
     for start, end, win in window_segments(windows):
         seg_params = jax.tree.map(lambda a: a[start:end], params["layers"])
-        seg_cache = jax.tree.map(lambda a: a[start:end], cache["main"])
 
         def body(carry, xs, _win=win):
-            hh = carry
-            lp, lc = xs
-            hh, lc = layer_decode(ctx, lp, _win, lc, hh, pos, pos3)
-            return constrain_batch(ctx, hh), lc
+            hh, kv = carry
+            lp, i = xs
+            hh, kv = layer_decode(ctx, lp, _win, kv, i, hh, pos, pos3)
+            return (constrain_batch(ctx, hh), kv), None
 
-        h, seg_out = jax.lax.scan(body, h, (seg_params, seg_cache))
-        seg_caches.append(seg_out)
-    with jax.named_scope("kv_write"):
-        new_cache["main"] = jax.tree.map(
-            lambda *xs: jnp.concatenate(xs, axis=0), *seg_caches
-        )
+        layer_ids = jnp.arange(start, end, dtype=jnp.int32)
+        (h, stack), _ = jax.lax.scan(body, (h, stack), (seg_params, layer_ids))
+    new_cache["main"] = stack
     new_cache["length"] = t + 1
     with jax.named_scope("head"):
         logits = unembed(ctx, params, h)

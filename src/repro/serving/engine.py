@@ -1,9 +1,10 @@
 """Serving engine: batched prefill + decode against a KV cache.
 
 ``make_prefill_step`` / ``make_serve_step`` are the jit-able step functions
-the multi-pod dry-run lowers; ``ServingEngine`` is the runnable host-side
-loop used by examples and by the WalltimeDevice (real measured throughput
-for the CORAL optimizer).
+the multi-pod dry-run lowers; ``make_engine_decode`` is the decode program
+``ServingEngine`` dispatches (its KV storage donated and updated in place);
+``ServingEngine`` is the runnable host-side loop used by examples and by
+the WalltimeDevice (real measured throughput for the CORAL optimizer).
 """
 from __future__ import annotations
 
@@ -40,12 +41,33 @@ def make_serve_step(ctx: ApplyCtx):
     return serve_step
 
 
+def make_engine_decode(ctx: ApplyCtx):
+    """The decode program ``ServingEngine`` dispatches:
+    ``(params, kv, length, tokens) -> (kv, length, logits)``, ``kv`` the
+    cache without ``length``. ``kv`` is donated, so the step writes the
+    new token into the given storage in place; ``length`` is not, so an
+    older handle keeps its own."""
+    step = make_serve_step(ctx)
+
+    def serve_step(params, kv, length, tokens):
+        cache, logits = step(params, {**kv, "length": length}, tokens)
+        length = cache.pop("length")
+        return cache, length, logits
+
+    return jax.jit(serve_step, donate_argnums=(1,))
+
+
 class ServingEngine:
     """Greedy-decoding engine over batch-aligned request groups.
 
     Concurrency (the CORAL knob ``c``) is modeled as multiple in-flight
     request groups: host-side token sampling/bookkeeping of group i
     overlaps device compute of group j, as on a real serving host.
+
+    ``decodes`` counts decode dispatches; ``kv_in_place`` counts those
+    whose given KV storage JAX consumed (donated). The two are equal
+    unless the backend could not donate, and then each step copies the
+    cache.
     """
 
     def __init__(self, ctx: ApplyCtx, params, batch_size: int, max_len: int):
@@ -54,7 +76,9 @@ class ServingEngine:
         self.batch = batch_size
         self.max_len = max_len
         self._prefill = jax.jit(make_prefill_step(ctx, capacity=max_len))
-        self._decode = jax.jit(make_serve_step(ctx))
+        self._decode = make_engine_decode(ctx)
+        self.decodes = 0
+        self.kv_in_place = 0
 
     def prefill(self, tokens: np.ndarray, extras: Optional[Dict] = None):
         batch = {"tokens": jnp.asarray(tokens)}
@@ -66,8 +90,27 @@ class ServingEngine:
     def decode(self, cache, tokens):
         """One decode step. Dispatch is asynchronous: the returned
         (cache, logits) are device futures, which is what lets the runtime
-        keep ``c`` groups in flight on the device queue."""
-        return self._decode(self.params, cache, tokens)
+        keep ``c`` groups in flight on the device queue.
+
+        The step consumes the KV storage of ``cache``: it is donated and
+        updated in place. The given handle then refers to the group's
+        updated storage at its own, unchanged ``length`` (every handle of
+        a group shares the per-stack dicts, which are rebound to the new
+        arrays). Decoding such a stale handle writes at its old slot and
+        attends to slots up to it; the later slots are masked, so its
+        attention reads as a functional decode of that handle would,
+        within capacity. Recurrent (SSM/conv) state has no slots: a stale
+        handle reads it as the latest decode of the group left it."""
+        kv = {k: v for k, v in cache.items() if k != "length"}
+        probe = jax.tree.leaves(kv)[0]
+        new_kv, length, logits = self._decode(
+            self.params, kv, cache["length"], tokens
+        )
+        self.decodes += 1
+        self.kv_in_place += probe.is_deleted()
+        for stack, leaves in new_kv.items():
+            kv[stack].update(leaves)
+        return {**kv, "length": length}, logits
 
     def generate(
         self,
@@ -83,7 +126,7 @@ class ServingEngine:
         tok = self._sample(logits, temperature, key)
         for i in range(n_tokens):
             out.append(np.asarray(tok))
-            cache, logits = self._decode(self.params, cache, tok)
+            cache, logits = self.decode(cache, tok)
             key, sub = jax.random.split(key)
             tok = self._sample(logits, temperature, sub)
         return np.concatenate(out, axis=1)

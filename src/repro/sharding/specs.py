@@ -145,21 +145,24 @@ def cache_shardings(mesh: Mesh, cfg, cache_tree, global_batch: int):
     """KV-cache sharding: batch dim over data axes; the cache sequence dim
     over "model" (flash-decode style: each model shard owns a slice of the
     context and the softmax reduction runs as a collective)."""
+    from repro.models.transformer import SLOT_AXIS
+
     da = data_axes(mesh)
     bsize = math.prod(mesh.shape[a] for a in da)
     bspec = da if (da and global_batch % bsize == 0) else None
 
-    def one(path_leaf):
-        leaf = path_leaf
+    def one(path, leaf):
         nd = len(leaf.shape)
         if nd == 0:  # length scalar
             return NamedSharding(mesh, P())
-        # layout (L, B, W, ...) for kv/latent; (L, B, ...) for ssm state
+        # (L, B, ...): the ring-slot axis of K/V (L, B, kv, W, hd) and of
+        # the MLA latent (L, B, W, r); axis 2 of the state leaves
+        axis = SLOT_AXIS.get(getattr(path[-1], "key", None), 2)
         parts = [None] * nd
         if nd >= 2:
             parts[1] = bspec
-        if nd >= 3 and leaf.shape[2] % mesh.shape["model"] == 0:
-            parts[2] = "model"
+        if nd > axis and leaf.shape[axis] % mesh.shape["model"] == 0:
+            parts[axis] = "model"
         return NamedSharding(mesh, P(*parts))
 
-    return jax.tree.map(one, cache_tree)
+    return jax.tree_util.tree_map_with_path(one, cache_tree)

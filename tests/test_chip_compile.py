@@ -9,6 +9,7 @@ this file loads the TPU compiler.
 """
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,11 @@ from repro.models.transformer import (
     abstract_cache,
     abstract_model_params,
 )
-from repro.serving.engine import make_prefill_step, make_serve_step
+from repro.serving.engine import (
+    make_engine_decode,
+    make_prefill_step,
+    make_serve_step,
+)
 
 HBM_BYTES = 15.75 * 2**30  # one v5e chip's usable HBM, as the compiler counts
 
@@ -151,3 +156,43 @@ def test_qwen_full_decode_fits_hbm(one_chip, qwen_full):
     tokens = _sds(one_chip, (BATCH, 1), jnp.int32)
     c = _compile(make_serve_step(ctx), params, cache, tokens)
     assert _hbm_bytes(c) <= HBM_BYTES, c.memory_analysis()
+
+
+OFFLINE_BATCH, OFFLINE_CAPACITY = 16, 1024  # the offline cells' decode
+_OP = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = bf16\[([\d,]*)\]", re.M)
+
+
+def _computation(text: str, name: str) -> str:
+    start = re.search(rf"^{re.escape(name)} ", text, re.M).start()
+    return text[start:text.index("\n}", start)]
+
+
+def test_qwen_full_engine_decode_updates_cache_in_place(one_chip, qwen_full):
+    """The decode the engine dispatches, at the offline cells' shapes:
+    the KV cache is aliased to the output, nothing copies the whole
+    cache, and the layer loop holds no per-layer K/V slab: attention reads
+    each layer's slab from the carried cache in place."""
+    ctx, params = qwen_full
+    cache = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        abstract_cache(ctx.cfg, OFFLINE_BATCH, OFFLINE_CAPACITY),
+    )
+    kv = {k: v for k, v in cache.items() if k != "length"}
+    tokens = _sds(one_chip, (OFFLINE_BATCH, 1), jnp.int32)
+    c = make_engine_decode(ctx).lower(
+        params, kv, cache["length"], tokens).compile()
+    k = cache["main"]["k"]
+    assert c.memory_analysis().alias_size_in_bytes >= 2 * k.size * 2
+    assert _hbm_bytes(c) <= HBM_BYTES, c.memory_analysis()
+
+    def dims(d):  # an output's dims, size-1 axes dropped, in any order
+        return sorted(int(x) for x in d.split(",") if x not in ("", "1"))
+
+    text = c.as_text()
+    copies = [n for n, d in _OP.findall(text)
+              if n.startswith("%copy") and dims(d) == sorted(k.shape)]
+    assert not copies, copies
+    (body,) = set(re.findall(r"body=(%[\w.\-]+)", text))
+    slabs = [n for n, d in _OP.findall(_computation(text, body))
+             if dims(d) == sorted(k.shape[1:])]
+    assert not slabs, slabs
