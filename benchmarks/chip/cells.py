@@ -9,21 +9,29 @@ the name that ``BENCHMARK.json`` gives it:
 - ``limits/<cell>.json``: the limit on each number the correctness check
   compares, with the readings it was set from;
 - ``layer_metrics/<metric>.py``: the reader of one per-layer metric;
-- ``references/<name>.py``: a plain reference, named by the config.
+- ``references/<name>.py``: a plain reference, named by the config;
+- ``programs/<name>.py``: how the system under test is built from a
+  configuration file (its ``ModelConfig``, the scale of each weight, the
+  cut the CPU tests use, and in ``RUN_CONFIG`` the serving options it
+  needs, as a mixture of experts such as granite-4.0-h-small will need
+  ``moe_impl``), named by the config's ``"program"`` key, or
+  ``dense_gqa`` where it has none.
 
 So a cell, a mix or a metric is added with new files and entries only.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
 from types import ModuleType
-from typing import List
+from typing import List, Optional
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+DEFAULT_PROGRAM = "dense_gqa"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,8 +51,8 @@ class Cell:
     per_layer: List[Metric]
 
 
-def load_benchmark(root: Path = ROOT) -> dict:
-    return json.loads((root / "BENCHMARK.json").read_text())
+def load_benchmark(root: Optional[Path] = None) -> dict:
+    return json.loads(((root or ROOT) / "BENCHMARK.json").read_text())
 
 
 def _applies(entry: dict, cell: str) -> bool:
@@ -55,9 +63,10 @@ def _metric(entry: dict) -> Metric:
     return Metric(entry["name"], entry["unit"])
 
 
-def resolve(name: str, root: Path = ROOT) -> Cell:
+def resolve(name: str, root: Optional[Path] = None) -> Cell:
     """The cell ``name`` with its configuration, traffic mix, limits and
     the metrics it reports; a missing file raises."""
+    root = root or ROOT
     bench = load_benchmark(root)
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -78,9 +87,12 @@ def resolve(name: str, root: Path = ROOT) -> Cell:
     )
 
 
+@functools.lru_cache(maxsize=None)
 def load_module(path: Path) -> ModuleType:
-    """Import one file by path (metric and reference names hold dots)."""
-    mod_name = "chipbench_" + path.stem.replace(".", "_").replace("-", "_")
+    """Import one file by path (metric and reference names hold dots),
+    once per path; the module is named by its directory and file."""
+    stem = f"{path.parent.name}_{path.stem}".replace(".", "_").replace("-", "_")
+    mod_name = "chipbench_" + stem
     spec = importlib.util.spec_from_file_location(mod_name, path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
@@ -93,3 +105,7 @@ def layer_reader(name: str) -> ModuleType:
 
 def reference_module(config: dict) -> ModuleType:
     return load_module(HERE / "references" / f"{config['reference']}.py")
+
+
+def program_module(config: dict) -> ModuleType:
+    return load_module(HERE / "programs" / f"{config.get('program', DEFAULT_PROGRAM)}.py")

@@ -27,12 +27,14 @@ class Dims:
 
     @classmethod
     def of(cls, config: dict) -> "Dims":
+        """The sizes of a configuration file; the head size is its
+        ``head_dim`` where it gives one, else hidden ÷ query heads."""
         d, hq = int(config["hidden_size"]), int(config["num_attention_heads"])
         return cls(
             d=d,
             hq=hq,
             hkv=int(config["num_key_value_heads"]),
-            hd=d // hq,
+            hd=int(config.get("head_dim") or d // hq),
             ff=int(config["intermediate_size"]),
             vocab=int(config["vocab_size"]),
             layers=int(config["num_hidden_layers"]),

@@ -4,6 +4,10 @@ The weights are the benchmark's own: drawn on the device from the run's
 seed, in bfloat16, in one jitted call, laid out as the program's
 parameter tree. The program serves them; the plain reference reads the
 same arrays. Nothing of the program's own initialisation is used.
+
+What differs by model family (the ``ModelConfig`` a configuration file
+maps to, the scale of each weight) comes from the configuration's
+program module, ``programs/<name>.py`` (``cells.program_module``).
 """
 from __future__ import annotations
 
@@ -11,40 +15,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# standard deviation of each kind of leaf, by the leaf's name; matrices
-# not listed get 1/sqrt(fan_in), which keeps every projection's output
-# near unit scale at any width
-_NORMS = ("ln1", "ln2", "final_norm")  # 1 + 0.1·N(0, 1)
-_BIASES = ("bq", "bk", "bv")  # 0.5·N(0, 1): large enough to matter
-_EMBED = "embed"  # 1/sqrt(d): unit-scale logits through a tied head
+import cells
 
 
 def program_config(config: dict):
     """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-
-    return ModelConfig(
-        name=config.get("model_type", "dense"),
-        arch_type="dense",
-        n_layers=int(config["num_hidden_layers"]),
-        d_model=int(config["hidden_size"]),
-        n_heads=int(config["num_attention_heads"]),
-        n_kv_heads=int(config["num_key_value_heads"]),
-        d_ff=int(config["intermediate_size"]),
-        vocab=int(config["vocab_size"]),
-        qkv_bias=bool(config["qkv_bias"]),
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        source=config["source"],
-    )
+    return cells.program_module(config).program_config(config)
 
 
-def run_config():
-    """The serving path's run configuration, with bfloat16 weights."""
+def run_config(config: dict, **overrides):
+    """The serving path's run configuration, with bfloat16 weights and
+    what the configuration's program asks for."""
     from repro.configs.runtime import serving_config
 
-    return serving_config(param_dtype="bfloat16")
+    program = cells.program_module(config)
+    return serving_config(param_dtype="bfloat16",
+                          **{**getattr(program, "RUN_CONFIG", {}), **overrides})
 
 
 def seed_key(seed: int) -> np.ndarray:
@@ -52,31 +38,38 @@ def seed_key(seed: int) -> np.ndarray:
     return np.random.SeedSequence(int(seed)).generate_state(2, np.uint32)
 
 
-def _leaf_init(path, leaf, key):
+def _leaf_init(path, leaf, key, rule):
+    """One leaf from its own key: a standard-normal draw that the
+    program's ``leaf_init`` scales, or 1/sqrt(fan_in) where it declines."""
     name = str(getattr(path[-1], "key", path[-1]))
     shape, dt = leaf.shape, leaf.dtype
     z = jax.random.normal(key, shape, jnp.float32)
-    if name in _NORMS:
-        return (1.0 + 0.1 * z).astype(dt)
-    if name in _BIASES:
-        return (0.5 * z).astype(dt)
-    if name == _EMBED:
-        return (z / np.sqrt(shape[-1])).astype(dt)
-    return (z / np.sqrt(shape[-2])).astype(dt)
+    value = rule(name, shape, z)
+    if value is None:
+        value = z / np.sqrt(shape[-2])
+    return value.astype(dt)
 
 
-def make_weights(abstract_params, seed: int):
-    """All weights from ``seed``, on the device, in one jitted call."""
-    paths, treedef = jax.tree_util.tree_flatten_with_path(abstract_params)
+def weight_builder(config: dict, abstract_params):
+    """The function from key data to every leaf of ``abstract_params``,
+    in tree order, each drawn by the configuration's program."""
+    paths, _ = jax.tree_util.tree_flatten_with_path(abstract_params)
+    rule = cells.program_module(config).leaf_init
 
-    @jax.jit
     def build(key_data):
         base = jax.random.wrap_key_data(key_data)
         return [
-            _leaf_init(path, leaf, jax.random.fold_in(base, i))
+            _leaf_init(path, leaf, jax.random.fold_in(base, i), rule)
             for i, (path, leaf) in enumerate(paths)
         ]
 
+    return build
+
+
+def make_weights(config: dict, abstract_params, seed: int):
+    """All weights from ``seed``, on the device, in one jitted call."""
+    treedef = jax.tree_util.tree_structure(abstract_params)
+    build = jax.jit(weight_builder(config, abstract_params))
     return jax.tree_util.tree_unflatten(treedef, build(jnp.asarray(seed_key(seed))))
 
 
@@ -86,8 +79,8 @@ def build_engine(config: dict, mix: dict, seed: int):
     from repro.serving.engine import ServingEngine
 
     cfg = program_config(config)
-    rcfg = run_config()
-    params = make_weights(abstract_model_params(cfg, rcfg), seed)
+    rcfg = run_config(config)
+    params = make_weights(config, abstract_model_params(cfg, rcfg), seed)
     return ServingEngine(ApplyCtx(cfg, rcfg, None), params, int(mix["batch"]),
                          max_len(mix))
 

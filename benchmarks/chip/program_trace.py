@@ -335,7 +335,6 @@ def measure(cell, seed: int, seconds: float, trace: bool, trace_s: float,
     import shutil
     import tempfile
 
-    import counts
     import run
 
     trace_s = min(trace_s, seconds)
@@ -343,8 +342,7 @@ def measure(cell, seed: int, seconds: float, trace: bool, trace_s: float,
     with metadata_in_cache_key():
         s = run.serve(cell, seed, seconds, trace_dir, wrap=ProgramCalls, trace_s=trace_s)
     w = s.window
-    rec = reading.RunRecord(cell.config, counts.Dims.of(cell.config), cell.traffic,
-                            w, s.proxy, peaks)
+    rec = reading.RunRecord(cell.config, cell.traffic, w, s.proxy, peaks)
     last = w.t1 - trace_s
     out = {
         "cell": cell.name, "seed": seed, "seconds": seconds, "trace": int(trace),

@@ -3,6 +3,7 @@ metrics themselves (host clock)."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -15,7 +16,6 @@ from drive import StampingEngine, WindowResult
 @dataclasses.dataclass
 class RunRecord:
     config: dict
-    dims: counts.Dims
     mix: dict
     window: WindowResult
     engine: StampingEngine
@@ -24,6 +24,14 @@ class RunRecord:
     trace_lo: float = 0.0  # the traced window on the trace's clock
     trace_hi: float = 0.0
     trace_stop: float = 0.0  # when tracing stopped, on the host's clock
+
+    @functools.cached_property
+    def dims(self) -> counts.Dims:
+        """The dense decoder's sizes that the FLOP and byte counts take,
+        worked out when a reader first asks: a configuration of another
+        family need not have them, and a run that reads no count never
+        asks."""
+        return counts.Dims.of(self.config)
 
     def window_tokens(self) -> List[Tuple[object, int, float]]:
         """(tracked request, token index, stamp) of every token in the window."""

@@ -45,7 +45,6 @@ def main(cell_name: str) -> int:
 
     import cells
     import model
-    from repro.configs.runtime import serving_config
     from repro.models.transformer import (
         ApplyCtx,
         abstract_cache,
@@ -67,7 +66,7 @@ def main(cell_name: str) -> int:
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     cfg = model.program_config(cell.config)
-    rcfg = serving_config(param_dtype="bfloat16", use_pallas=True)
+    rcfg = model.run_config(cell.config, use_pallas=True)
     ctx = ApplyCtx(cfg, rcfg, None)
     abstract = abstract_model_params(cfg, rcfg)
     params = jax.tree.map(lambda a: sds(a.shape, a.dtype), abstract)
@@ -75,14 +74,8 @@ def main(cell_name: str) -> int:
     report = {"cell": cell_name, "layers": cfg.n_layers,
               "params": int(sum(a.size for a in jax.tree.leaves(abstract)))}
 
-    paths, _ = jax.tree_util.tree_flatten_with_path(abstract)
-
-    def build(key_data):
-        base = jax.random.wrap_key_data(key_data)
-        return [model._leaf_init(p, leaf, jax.random.fold_in(base, i))
-                for i, (p, leaf) in enumerate(paths)]
-
-    programs = {"weights": jax.jit(build).lower(sds((2,), jnp.uint32)).compile()}
+    build = jax.jit(model.weight_builder(cell.config, abstract))
+    programs = {"weights": build.lower(sds((2,), jnp.uint32)).compile()}
     for seq in sorted(set(int(p) for p in cell.traffic["prompt_lens"])):
         step = make_prefill_step(ctx, capacity=cap)
         programs[f"prefill_{batch}x{seq}"] = jax.jit(

@@ -38,7 +38,6 @@ for p in (str(HERE), str(ROOT / "src")):
 import numpy as np  # noqa: E402
 
 import cells  # noqa: E402
-import counts  # noqa: E402
 
 TRACE_S = 8.0  # seconds at the end of the window that a --trace 1 run profiles
 KEEP_EVERY = 16  # one decode step in this many keeps its logits for the check
@@ -192,8 +191,7 @@ def run_cell(
     trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
     s = serve(cell, seed, seconds, trace_dir, wrap, trace_s)
     window = s.window
-    run = reading.RunRecord(cell.config, counts.Dims.of(cell.config), cell.traffic,
-                            window, s.proxy, peaks)
+    run = reading.RunRecord(cell.config, cell.traffic, window, s.proxy, peaks)
     metrics = {}
     if not trace:
         for m in cell.end_to_end:

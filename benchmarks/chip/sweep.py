@@ -26,7 +26,6 @@ def main(argv) -> int:
     if jax.devices()[0].platform != "tpu":
         print("sweep.py: needs a TPU", file=sys.stderr)
         return 2
-    import counts
     import drive
     import model
     import reading
@@ -41,7 +40,7 @@ def main(argv) -> int:
     for rate in (float(a) for a in argv[2:]):
         mix = dict(cell.traffic, rate_rps=rate)
         w = drive.run_window(engine, mix, 0, seconds, vocab)
-        rec = reading.RunRecord(cell.config, counts.Dims.of(cell.config), mix, w, engine)
+        rec = reading.RunRecord(cell.config, mix, w, engine)
         due = rec.due_in_window()
         done = [tr for tr in w.tracked
                 if tr.stamps and len(tr.stamps) == tr.planned.n_out

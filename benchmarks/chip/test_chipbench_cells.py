@@ -46,6 +46,23 @@ def test_every_file_is_named_by_an_entry():
     assert {str(p.relative_to(cells.ROOT)) for p in (HERE / "configs").glob("*.json")} == files
 
 
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_config_names_a_program(name):
+    entry = next(c for c in BENCH["configs"] if c["name"] == name)
+    config = json.loads((cells.ROOT / entry["file"]).read_text())
+    program = cells.program_module(config)
+    for attr in ("program_config", "leaf_init", "tiny"):
+        assert callable(getattr(program, attr))
+    assert isinstance(getattr(program, "RUN_CONFIG", {}), dict)
+
+
+def test_every_program_is_named_by_a_config_or_is_the_default():
+    named = {json.loads((cells.ROOT / c["file"]).read_text()).get(
+        "program", cells.DEFAULT_PROGRAM) for c in BENCH["configs"]}
+    assert {p.stem for p in (HERE / "programs").glob("*.py")} == named | {
+        cells.DEFAULT_PROGRAM}
+
+
 def test_config_files_state_their_cuts():
     for c in BENCH["configs"]:
         config = json.loads((cells.ROOT / c["file"]).read_text())

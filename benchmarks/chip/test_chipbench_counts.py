@@ -33,6 +33,15 @@ def test_granite_dims_and_parameters():
     assert counts.weight_bytes(d) == 2 * (18 * per_layer + 4096 * 49152)
 
 
+def test_head_dim_from_the_file_where_it_gives_one():
+    config = json.loads((HERE / "configs" / "qwen2.5-3b.json").read_text())
+    assert "head_dim" not in config and counts.Dims.of(config).hd == 2048 // 16
+    d = counts.Dims.of(dict(config, head_dim=64))
+    assert d.hd == 64
+    # q and o 2048x(16*64), k and v 2048x(2*64), three 2048x11008
+    assert d.layer_matmul_params == 2 * 2048 * 1024 + 2 * 2048 * 128 + 3 * 2048 * 11008
+
+
 def test_prefill_flops_by_hand_qwen():
     d = _dims("qwen2.5-3b")
     b, s = 16, 512

@@ -134,7 +134,7 @@ class _Request:
 
 def _run(trace, tracked=()) -> reading.RunRecord:
     window = WindowResult(0.0, 10.0, [Tracked(None, r, 0.0) for r in tracked], 0, 16)
-    return reading.RunRecord({}, None, {}, window, None, None, trace, 0.0, 10.0)
+    return reading.RunRecord({}, {}, window, None, None, trace, 0.0, 10.0)
 
 
 def test_readings_of_the_program_spans():

@@ -15,14 +15,12 @@ sys.path.insert(0, str(HERE))
 import cells  # noqa: E402
 import model  # noqa: E402
 
-SMALL = dict(hidden_size=128, intermediate_size=256, num_hidden_layers=2,
-             num_attention_heads=4, num_key_value_heads=2, vocab_size=512)
-
 
 @pytest.fixture(scope="module", params=["qwen2.5-3b", "granite-8b"])
 def small(request):
-    """A reduced copy of each configuration with float32 weights drawn by
-    the benchmark, the program's float32 forward pass, and the reference."""
+    """A reduced copy of each configuration (its program's CPU cut) with
+    float32 weights drawn by the benchmark, the program's float32 forward
+    pass, and the reference."""
     from repro.configs.runtime import RunConfig
     from repro.models.transformer import (
         ApplyCtx,
@@ -31,10 +29,10 @@ def small(request):
     )
 
     config = json.loads((HERE / "configs" / f"{request.param}.json").read_text())
-    config.update(SMALL)
+    config = cells.program_module(config).tiny(config)
     cfg = model.program_config(config)
     rcfg = RunConfig(param_dtype="float32", compute_dtype="float32", remat="none")
-    params = model.make_weights(abstract_model_params(cfg, rcfg), 2**31 + 3)
+    params = model.make_weights(config, abstract_model_params(cfg, rcfg), 2**31 + 3)
     ctx = ApplyCtx(cfg, rcfg, None)
 
     def program_logits(tokens):

@@ -30,12 +30,10 @@ def test_no_tpu_exits_nonzero_without_a_result():
 
 
 def _tiny(name: str) -> cells.Cell:
-    """The cell with its widths and lengths cut to what a CPU test holds;
-    the limit stays the cell's own."""
+    """The cell with its widths (by its program's own cut) and lengths cut
+    to what a CPU test holds; the limit stays the cell's own."""
     cell = cells.resolve(name)
-    config = dict(cell.config, hidden_size=128, intermediate_size=256,
-                  num_hidden_layers=2, num_attention_heads=4,
-                  num_key_value_heads=2, vocab_size=512)
+    config = cells.program_module(cell.config).tiny(cell.config)
     mix = dict(cell.traffic, prompt_lens=[16, 32][: len(cell.traffic["prompt_lens"])],
                batch=4, output_lens=dict(cell.traffic["output_lens"], lo=4, hi=12),
                lead_s=0.5)
