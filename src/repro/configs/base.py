@@ -25,6 +25,23 @@ class MoEConfig:
     # index of first MoE layer; earlier layers use a dense FFN of d_ff
     first_moe_layer: int = 0
     router_aux_loss_coef: float = 0.001
+    # a chip's share under expert parallelism: the router scores
+    # ``n_routed`` experts (None: ``n_experts``), of which this layer holds
+    # ``n_experts``, ids ``first_held`` onwards
+    n_routed: Optional[int] = None
+    first_held: int = 0
+    # width of the shared expert (None: ``d_ff_expert`` × ``n_shared_experts``)
+    d_ff_shared: Optional[int] = None
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed if self.n_routed is not None else self.n_experts
+
+    @property
+    def shared_width(self) -> int:
+        if self.d_ff_shared is not None:
+            return self.d_ff_shared
+        return self.d_ff_expert * self.n_shared_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,11 +91,26 @@ class ModelConfig:
     # attention details
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
-    rope_type: str = "rope"  # rope | mrope | none (e.g. whisper: learned pos)
+    # rope | mrope | none (sinusoids added to the embedding, e.g. whisper)
+    # | nope (no position at all: granite-4.0-h's attention)
+    rope_type: str = "rope"
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)  # t/h/w (qwen2-vl)
     qk_norm: bool = False
     sliding_window: Optional[int] = None  # SWA width for hybrid archs
     global_attn_every: int = 0  # 0 = never (all SWA) unless sliding_window None
+    # softmax scale of attention (None: 1/sqrt(head_dim))
+    attention_multiplier: Optional[float] = None
+
+    # per-layer mixer kind, "mamba" or "attention" (granite-4.0-h); None:
+    # ``arch_type`` gives every layer the same mixer
+    layer_types: Optional[Tuple[str, ...]] = None
+
+    # muP multipliers (granite): the embedding is scaled by the first, each
+    # mixer's and FFN's output by the second before it joins the residual,
+    # and the logits are divided by the third
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # optional blocks
     moe: Optional[MoEConfig] = None
@@ -98,6 +130,16 @@ class ModelConfig:
     tie_embeddings: bool = False
 
     source: str = ""  # citation for the config numbers
+
+    def __post_init__(self):
+        if self.layer_types is not None and (
+            len(self.layer_types) != self.n_layers
+            or not set(self.layer_types) <= {"mamba", "attention"}
+        ):
+            raise ValueError(
+                f"layer_types must give each of {self.n_layers} layers 'mamba' "
+                f"or 'attention': {self.layer_types}"
+            )
 
     # ---------------------------------------------------------------- utils
     @property
@@ -149,13 +191,16 @@ class ModelConfig:
                 per = 3 * d * e.d_ff_expert
                 return (
                     e.n_experts * per
-                    + e.n_shared_experts * per
-                    + d * e.n_experts  # router
+                    + 3 * d * e.shared_width
+                    + d * e.router_width  # router
                 )
             return 3 * d * self.d_ff  # gate/up/down
 
         for layer in range(self.n_layers):
-            if self.arch_type == "ssm":
+            if self.layer_types is not None:
+                mamba = self.layer_types[layer] == "mamba"
+                p += ssm_params() if mamba else attn_params()
+            elif self.arch_type == "ssm":
                 p += ssm_params()
             elif self.arch_type == "hybrid":
                 p += attn_params() + ssm_params()

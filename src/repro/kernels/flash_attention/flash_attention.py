@@ -82,7 +82,7 @@ def _flash_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "window", "block_q", "block_k", "interpret"),
+    static_argnames=("causal", "window", "block_q", "block_k", "interpret", "scale"),
 )
 def flash_attention_bhsd(
     q: jax.Array,  # (B, Hq, Sq, D)
@@ -94,7 +94,9 @@ def flash_attention_bhsd(
     block_q: int = 512,
     block_k: int = 512,
     interpret: bool | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
+    """``scale``: the softmax scale, 1/sqrt(D) when None."""
     if interpret is None:  # static param: resolved at trace time
         interpret = default_interpret()
     b, hq, sq, d = q.shape
@@ -110,7 +112,8 @@ def flash_attention_bhsd(
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pk), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pk), (0, 0)))
     nq, nk = (sq + pq) // bq, (sk + pk) // bk
-    scale = 1.0 / (d**0.5)
+    if scale is None:
+        scale = 1.0 / (d**0.5)
 
     out = pl.pallas_call(
         functools.partial(

@@ -20,6 +20,7 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     interpret: bool | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     b, sq, hkv, g, d = qg.shape
     q = qg.reshape(b, sq, hkv * g, d).transpose(0, 2, 1, 3)
@@ -27,6 +28,6 @@ def flash_attention(
     vv = v.transpose(0, 2, 1, 3)
     o = flash_attention_bhsd(
         q, kk, vv, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=interpret,
+        block_q=block_q, block_k=block_k, interpret=interpret, scale=scale,
     )
     return o.transpose(0, 2, 1, 3).reshape(b, sq, hkv, g, v.shape[-1])

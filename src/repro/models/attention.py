@@ -175,23 +175,28 @@ def attention(
     causal: bool = True,
     window: Optional[int] = None,
     rcfg: RunConfig = RunConfig(),
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """Grouped-query attention. Returns (B, Sq, Hq, D)."""
+    """Grouped-query attention. Returns (B, Sq, Hq, D). ``scale``: the
+    softmax scale, 1/sqrt(D) when None."""
     b, sq, hq, d = q.shape
     hkv = k.shape[2]
     assert hq % hkv == 0, (hq, hkv)
     g = hq // hkv
     qg = q.reshape(b, sq, hkv, g, d)
-    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
-
     if rcfg.use_pallas and sq > 1:
         from repro.kernels.flash_attention import ops as fa_ops
 
         out = fa_ops.flash_attention(
             qg, k, v, q_pos, kv_pos, causal=causal, window=window,
-            block_q=rcfg.attn_block_q, block_k=rcfg.attn_block_k,
+            block_q=rcfg.attn_block_q, block_k=rcfg.attn_block_k, scale=scale,
         )
-    elif sq * k.shape[1] > rcfg.attn_blocked_threshold**2:
+        return out.reshape(b, sq, hq, v.shape[-1]).astype(v.dtype)
+    scale = (
+        1.0 / jnp.sqrt(d).astype(jnp.float32) if scale is None
+        else jnp.float32(scale)
+    )
+    if sq * k.shape[1] > rcfg.attn_blocked_threshold**2:
         if (
             causal
             and isinstance(window, int)

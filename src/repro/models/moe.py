@@ -1,6 +1,6 @@
 """Mixture-of-Experts FFN.
 
-Two implementations:
+Two implementations, and a third for a layer that holds a share:
   * ``dense``            — compute-all-experts + one-hot combine. Exact and
                            differentiable; used for reduced smoke configs
                            and single-device runs (its FLOP waste is E/k×).
@@ -12,8 +12,15 @@ Two implementations:
                            weighted combine → all_gather. Exactly two
                            all-to-alls per MoE layer, matching the
                            collective roofline of a real MoE pod.
+  * held                 — a chip's share under expert parallelism, without
+                           the exchange: routes over every expert, keeps the
+                           (token, expert) pairs whose expert it holds and
+                           runs each held expert over its own rows as a
+                           grouped matrix product. No token is dropped.
+                           Taken whenever the router scores more experts
+                           than the layer holds, whatever ``moe_impl`` says.
 
-Token dropping follows the standard fixed-capacity model
+Token dropping in ``expert_parallel`` follows the standard fixed-capacity model
 (capacity = ceil(T_sub·k·cf / E)).
 """
 from __future__ import annotations
@@ -37,7 +44,7 @@ def moe_param_specs(cfg: ModelConfig, n_layers: int) -> dict:
     L = (n_layers,)
     lx = ("layers",)
     specs = {
-        "router": ParamSpec(L + (d, e.n_experts), lx + ("embed", None)),
+        "router": ParamSpec(L + (d, e.router_width), lx + ("embed", None)),
         "we_gate": ParamSpec(
             L + (e.n_experts, d, e.d_ff_expert), lx + ("experts", "embed", "ff")
         ),
@@ -49,7 +56,7 @@ def moe_param_specs(cfg: ModelConfig, n_layers: int) -> dict:
         ),
     }
     if e.n_shared_experts:
-        ff_sh = e.d_ff_expert * e.n_shared_experts
+        ff_sh = e.shared_width
         specs.update(
             {
                 "ws_gate": ParamSpec(L + (d, ff_sh), lx + ("embed", "ff")),
@@ -307,6 +314,89 @@ def moe_ffn_ep2d(
     return out
 
 
+# ---------------------------------------------------------------------------
+# Held-expert path (a chip's share of an expert-parallel layer)
+# ---------------------------------------------------------------------------
+
+HELD_BLOCK = 2048  # tokens routed together: bounds the gathered rows
+
+
+def _tile(size: int, most: int) -> int:
+    """The largest multiple of 128 that divides ``size``, at most ``most``."""
+    return max(t for t in range(128, min(size, most) + 1, 128) if size % t == 0)
+
+
+def _grouped(rows, w, sizes, tm: int, pallas: bool):
+    """rows (m, k) sorted by group, w (G, k, n), sizes (G,): each group's
+    rows times its matrix. Rows past sum(sizes) are left undefined. The
+    kernel's weight tiles are large (up to 2048 × 768 at tm = 128), so a
+    decode's few rows per expert stream each expert's matrix in a few
+    steps."""
+    if pallas:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        from repro.kernels.runtime import default_interpret
+
+        most = 2048 if tm == 128 else 1024  # the tiles fit the scoped VMEM
+        k, n = w.shape[1:]
+        return gmm(rows, w.astype(rows.dtype), sizes,
+                   preferred_element_type=rows.dtype,
+                   tiling=(tm, _tile(k, most), _tile(n, most)),
+                   interpret=default_interpret())
+    return jax.lax.ragged_dot(rows, w.astype(rows.dtype), sizes)
+
+
+def _held_block(cfg: ModelConfig, p: dict, xt: jax.Array, pallas: bool) -> jax.Array:
+    """The held experts' gated sum for the tokens xt (T, d), float32."""
+    e = cfg.moe
+    t, d = xt.shape
+    held = e.n_experts
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                        p["router"].astype(jnp.float32))
+    top, ids = jax.lax.top_k(logits, e.top_k)
+    gates = jax.nn.softmax(top, axis=-1)  # over the chosen experts only
+    local = (ids - e.first_held).reshape(-1)
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True)  # held pairs by expert, the rest last
+    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    # a token holds at most min(k, held) of the pairs kept: every kept pair
+    # has a row; the rows fill whole tiles of the grouped kernel
+    tm = 512 if t >= 512 else 128
+    m = -(-t * min(e.top_k, held) // tm) * tm
+    order = jnp.pad(order, (0, max(0, m - order.size)))[:m]
+    token = order // e.top_k
+    valid = jnp.arange(m) < sizes.sum()
+    rows = xt[token]
+    g = _grouped(rows, p["we_gate"], sizes, tm, pallas)
+    u = _grouped(rows, p["we_up"], sizes, tm, pallas)
+    y = _grouped(jax.nn.silu(g) * u, p["we_down"], sizes, tm, pallas)
+    w = jnp.where(valid, gates.reshape(-1)[order], 0.0)
+    y = jnp.where(valid[:, None], y.astype(jnp.float32), 0.0) * w[:, None]
+    return jnp.zeros((t, d), jnp.float32).at[token].add(y)
+
+
+def moe_ffn_held(
+    cfg: ModelConfig, rcfg: RunConfig, p: dict, x: jax.Array
+) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of the layer plus the shared expert, for
+    x (B, S, d); tokens go through the router in blocks of ``HELD_BLOCK``.
+    With ``use_pallas`` the grouped products run in the megablox kernel
+    (interpreted off a TPU), else in ``lax.ragged_dot``."""
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    t = b * s
+    if t <= HELD_BLOCK:
+        y = _held_block(cfg, p, xt, rcfg.use_pallas)
+    else:
+        nb = -(-t // HELD_BLOCK)
+        xb = jnp.pad(xt, ((0, nb * HELD_BLOCK - t), (0, 0)))
+        y = jax.lax.map(lambda blk: _held_block(cfg, p, blk, rcfg.use_pallas),
+                        xb.reshape(nb, HELD_BLOCK, d))
+        y = y.reshape(nb * HELD_BLOCK, d)[:t]
+    y = y.astype(x.dtype) + _shared(p, xt)
+    return y.reshape(b, s, d), jnp.zeros((), jnp.float32)
+
+
 def moe_ffn(
     cfg: ModelConfig,
     rcfg: RunConfig,
@@ -314,6 +404,8 @@ def moe_ffn(
     p: dict,
     x: jax.Array,
 ) -> Tuple[jax.Array, jax.Array]:
+    if cfg.moe.router_width != cfg.moe.n_experts:
+        return moe_ffn_held(cfg, rcfg, p, x)
     impl = rcfg.moe_impl
     if impl == "auto":
         impl = (

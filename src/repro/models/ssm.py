@@ -133,12 +133,16 @@ def mamba2_forward(
     x: jax.Array,  # (B,S,d)
     rcfg: RunConfig,
     initial_state=None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Full-sequence Mamba2 block. Returns (out (B,S,d), final_state)."""
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Full-sequence Mamba2 block. Returns (out (B,S,d), final_state,
+    conv_tail): the tail is the last d_conv - 1 conv inputs (zeros before
+    the first position), the decode's conv state."""
     s = cfg.ssm
     proj = jnp.einsum("bsd,de->bse", x, p["in_proj"].astype(x.dtype))
     z, xi, Bm, Cm, dt, di, nh = _split_proj(cfg, proj)
     xbc = jnp.concatenate([xi, Bm, Cm], axis=-1)
+    k = s.d_conv - 1
+    tail = jnp.pad(xbc, ((0, 0), (max(0, k - xbc.shape[1]), 0), (0, 0)))[:, -k:]
     xbc = _causal_conv(xbc, p["conv_w"].astype(x.dtype), p["conv_b"].astype(x.dtype))
     xi, Bm, Cm = jnp.split(xbc, [di, di + s.d_state], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"][None, None, :])
@@ -155,7 +159,7 @@ def mamba2_forward(
     y = y.reshape(*x.shape[:2], di)
     y = rms_norm(y * jax.nn.silu(z), p["norm_w"], cfg.norm_eps)
     out = jnp.einsum("bse,ed->bsd", y, p["out_proj"].astype(x.dtype))
-    return out, state
+    return out, state, tail
 
 
 def mamba2_decode(

@@ -6,7 +6,10 @@ Layer stacking follows the MaxText pattern: per-layer parameters carry a
 leading ``layers`` axis and the stack is applied with ``lax.scan`` (so a
 94-layer config lowers/compiles one layer body). Heterogeneous leading
 layers (dense-FFN prologue of DeepSeek/Moonlight MoE) are applied unrolled
-before the scan.
+before the scan. A config with per-layer kinds (``layer_types``: Mamba-2
+and attention mixers in a published pattern, granite-4.0-h) stacks each
+mixer over the layers of its kind and the norms and FFN over every
+layer, and runs one scan per maximal run of one kind (``kind_runs``).
 
 Three entry points per architecture:
   forward_train(ctx, params, batch)             -> (logits, aux)
@@ -16,9 +19,12 @@ Three entry points per architecture:
 ``prefill`` and ``decode_step`` name their work with ``jax.named_scope``,
 so a device trace can attribute each operation: ``embed``, ``attn`` (QKV,
 rope, attention, output projection), ``kv_write`` (every cache write),
-``mlp`` and ``head`` (final norm and unembed).
+``mlp`` and ``head`` (final norm and unembed); in a stack with per-layer
+kinds also ``ssm`` (the Mamba-2 mixer), ``ssm_write`` (its state and
+conv writes) and ``moe`` (router, experts, shared expert, combine).
 
-The cache stacks each leaf over its layers. Attention K/V are stored
+The cache stacks each leaf over its layers (with per-layer kinds, over
+the layers of the leaf's kind). Attention K/V are stored
 ``(L, B, kv_heads, W, head_dim)``, the order decode attention reads, so a
 layer's slab is a plain slice the attention fusion reads in place; MLA's
 latent ``ckv``/``krope`` are ``(L, B, W, r)``. ``decode_step`` carries the
@@ -179,6 +185,56 @@ def _layer_specs(cfg: ModelConfig, n: int, dense_ffn: bool) -> dict:
     return s
 
 
+MAMBA, ATTENTION = "mamba", "attention"
+# each mixer kind's parameter group under ``layers``, stacked over the
+# layers of that kind; the norms and the FFN stack over every layer
+KIND_GROUP = {MAMBA: "mamba", ATTENTION: "attn"}
+
+
+def _kind_layer_specs(cfg: ModelConfig) -> dict:
+    n, kinds = cfg.n_layers, cfg.layer_types
+    s: dict = {}
+    s.update(_norm_specs(cfg, n, "ln1"))
+    s.update(_norm_specs(cfg, n, "ln2"))
+    s["ffn"] = _ffn_specs(cfg, n, dense=False)
+    if MAMBA in kinds:
+        s["mamba"] = ssm_lib.ssm_param_specs(cfg, kinds.count(MAMBA))
+    if ATTENTION in kinds:
+        s["attn"] = _attn_specs(cfg, kinds.count(ATTENTION))
+    return s
+
+
+def kind_runs(cfg: ModelConfig) -> list:
+    """[(start, end, kind, first)] for the maximal runs of one mixer kind
+    in ``cfg.layer_types``; ``first`` is the run's first index among the
+    layers of its kind (its row in that kind's parameters and cache)."""
+    runs, seen = [], {MAMBA: 0, ATTENTION: 0}
+    for start, end, kind in window_segments(list(cfg.layer_types)):
+        runs.append((start, end, kind, seen[kind]))
+        seen[kind] += end - start
+    return runs
+
+
+def _layer_params(layers: dict, i, j, kind: str) -> dict:
+    """Layer ``i``'s parameters: the norms and FFN at row ``i``, its
+    mixer at row ``j`` of its kind's stack. Indexed inside the layer scan,
+    so no run's weights are sliced out into a copy."""
+
+    def row(k):
+        return lambda a: jax.lax.dynamic_index_in_dim(a, k, 0, keepdims=False)
+
+    lp = {name: jax.tree.map(row(i), v)
+          for name, v in layers.items() if name not in KIND_GROUP.values()}
+    lp[KIND_GROUP[kind]] = jax.tree.map(row(j), layers[KIND_GROUP[kind]])
+    return lp
+
+
+def _run_ids(start: int, end: int, first: int):
+    """(layer ids, ids within the kind) of the run [start, end)."""
+    return (jnp.arange(start, end, dtype=jnp.int32),
+            jnp.arange(first, first + end - start, dtype=jnp.int32))
+
+
 def _n_prologue(cfg: ModelConfig) -> int:
     return cfg.moe.first_moe_layer if cfg.moe is not None else 0
 
@@ -189,7 +245,10 @@ def param_specs(cfg: ModelConfig) -> dict:
     specs: dict = {
         "embed": ParamSpec((cfg.vocab, d), ("vocab", "embed"), scale=d**0.5),
         "final_norm": ParamSpec((d,), (None,), init="ones"),
-        "layers": _layer_specs(cfg, cfg.n_layers - n_pro, dense_ffn=False),
+        "layers": (
+            _kind_layer_specs(cfg) if cfg.layer_types is not None
+            else _layer_specs(cfg, cfg.n_layers - n_pro, dense_ffn=False)
+        ),
     }
     if cfg.arch_type == "audio":
         specs["final_norm_b"] = ParamSpec((d,), (None,), init="zeros")
@@ -298,7 +357,7 @@ def attn_full(
     q, k = _rope_qk(cfg, q, k, positions, pos3)
     out = attention(
         q, k, v, positions, positions, causal=causal, window=window,
-        rcfg=ctx.rcfg,
+        rcfg=ctx.rcfg, scale=cfg.attention_multiplier,
     )
     out = out.reshape(*x.shape[:2], -1)
     return jnp.einsum("bse,ed->bsd", out, ap["wo"].astype(x.dtype)), (k, v)
@@ -335,11 +394,11 @@ def layer_full(
     aux = jnp.zeros((), jnp.float32)
     hn = _norm(cfg, lp, "ln1", h)
     if cfg.arch_type == "ssm":
-        out, state = ssm_lib.mamba2_forward(cfg, lp["ssm"], hn, ctx.rcfg)
+        out, state, tail = ssm_lib.mamba2_forward(cfg, lp["ssm"], hn, ctx.rcfg)
         if want_cache:
             cache["ssm"] = state.astype(jnp.bfloat16)
-            cache["conv"] = _conv_tail(cfg, hn, lp["ssm"])
-        return h + out, cache, aux
+            cache["conv"] = tail.astype(jnp.bfloat16)
+        return h + _residual(cfg, out), cache, aux
     if cfg.mla is not None:
         with jax.named_scope("attn"):
             attn_out, (latent, krope) = mla_full(
@@ -359,13 +418,13 @@ def layer_full(
                 cache["k"] = _to_cache("k", k.astype(jnp.bfloat16))
                 cache["v"] = _to_cache("v", v.astype(jnp.bfloat16))
     if cfg.arch_type == "hybrid":
-        ssm_out, state = ssm_lib.mamba2_forward(cfg, lp["ssm"], hn, ctx.rcfg)
+        ssm_out, state, tail = ssm_lib.mamba2_forward(cfg, lp["ssm"], hn, ctx.rcfg)
         g = jax.nn.sigmoid(lp["mix_gate"].astype(jnp.float32))
         attn_out = (g[0] * attn_out + g[1] * ssm_out).astype(hn.dtype)
         if want_cache:
             cache["ssm"] = state.astype(jnp.bfloat16)
-            cache["conv"] = _conv_tail(cfg, hn, lp["ssm"])
-    h = h + attn_out
+            cache["conv"] = tail.astype(jnp.bfloat16)
+    h = h + _residual(cfg, attn_out)
     if cfg.is_encoder_decoder and enc_out is not None:
         hc = _norm(cfg, lp, "ln_cross", h)
         c_out, (ck, cv) = cross_attn_full(ctx, lp["cross"], hc, enc_out, enc_pos)
@@ -376,7 +435,69 @@ def layer_full(
     hn2 = _norm(cfg, lp, "ln2", h)
     with jax.named_scope("mlp"):
         ff, aux = _ffn(ctx, lp["ffn"], hn2)
-    return h + ff, cache, aux
+    return h + _residual(cfg, ff), cache, aux
+
+
+def _residual(cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    """A mixer's or FFN's output as it joins the residual stream."""
+    return x * cfg.residual_multiplier if cfg.residual_multiplier != 1.0 else x
+
+
+def _ffn_scope(fp: dict) -> str:
+    return "moe" if "router" in fp else "mlp"
+
+
+def kind_layer_full(ctx: ApplyCtx, kind: str, lp: dict, h, positions, cache, i):
+    """One layer of a stack with per-layer kinds, over the whole sequence:
+    a Mamba-2 or an attention mixer, then the FFN. With ``cache`` (the
+    stacked leaves, as ``abstract_cache`` lays them out), the layer
+    writes its state at row ``i`` of its kind's leaves. Returns (h, aux)."""
+    cfg = ctx.cfg
+    hn = _norm(cfg, lp, "ln1", h)
+    if kind == MAMBA:
+        with jax.named_scope("ssm"):
+            out, state, tail = ssm_lib.mamba2_forward(cfg, lp["mamba"], hn, ctx.rcfg)
+        if cache is not None:
+            with jax.named_scope("ssm_write"):
+                _write(cache, "ssm", i, state)
+                _write(cache, "conv", i, tail)
+    else:
+        with jax.named_scope("attn"):
+            out, (k, v) = attn_full(ctx, lp["attn"], hn, positions, None, None)
+        if cache is not None:
+            with jax.named_scope("kv_write"):
+                _write(cache, "k", i, k, 0)
+                _write(cache, "v", i, v, 0)
+    h = h + _residual(cfg, out)
+    hn2 = _norm(cfg, lp, "ln2", h)
+    with jax.named_scope(_ffn_scope(lp["ffn"])):
+        ff, aux = _ffn(ctx, lp["ffn"], hn2)
+    return h + _residual(cfg, ff), aux
+
+
+def run_kinds(ctx: ApplyCtx, layers: dict, h, positions, cache=None):
+    """A stack with per-layer kinds over the whole sequence: one
+    ``lax.scan`` per maximal run of one kind, a run of one layer called
+    directly. ``cache``: zeroed leaves that each layer writes in place.
+    Returns (h, aux, cache)."""
+    aux = jnp.zeros((), jnp.float32)
+    for start, end, kind, first in kind_runs(ctx.cfg):
+
+        def body(carry, xs, _kind=kind):
+            hh, aux_c, c = carry
+            i, j = xs
+            c = None if c is None else dict(c)
+            lp = _layer_params(layers, i, j, _kind)
+            hh, aux_l = kind_layer_full(ctx, _kind, lp, hh, positions, c, j)
+            return (constrain_batch(ctx, hh), aux_c + aux_l, c), None
+
+        body = _maybe_remat(ctx.rcfg, body)
+        if end - start == 1:
+            (h, aux, cache), _ = body((h, aux, cache), (start, first))
+        else:
+            (h, aux, cache), _ = jax.lax.scan(
+                body, (h, aux, cache), _run_ids(start, end, first))
+    return h, aux, cache
 
 
 def _ffn(ctx: ApplyCtx, fp: dict, hn2: jax.Array):
@@ -391,20 +512,6 @@ def _ffn(ctx: ApplyCtx, fp: dict, hn2: jax.Array):
     else:
         ff = swiglu(hn2, fp["wg"], fp["wu"], fp["wd"])
     return ff, jnp.zeros((), jnp.float32)
-
-
-def _conv_tail(cfg: ModelConfig, hn: jax.Array, sp: dict) -> jax.Array:
-    """Last (d_conv-1) pre-activation conv inputs — the decode conv state."""
-    s = cfg.ssm
-    proj = jnp.einsum("bsd,de->bse", hn, sp["in_proj"].astype(hn.dtype))
-    di = s.inner(cfg.d_model)
-    xbc = proj[..., di : 2 * di + 2 * s.d_state]
-    k = s.d_conv - 1
-    tail = xbc[:, -k:, :]
-    pad = k - tail.shape[1]
-    if pad > 0:
-        tail = jnp.pad(tail, ((0, 0), (pad, 0), (0, 0)))
-    return tail.astype(jnp.bfloat16)
 
 
 # ---------------------------------------------------------------------------
@@ -513,6 +620,8 @@ def embed(ctx: ApplyCtx, params, tokens, positions, vision_embeds=None):
         h = jnp.concatenate([vision_embeds.astype(h.dtype), h[:, nv:]], axis=1)
     if cfg.rope_type == "none" and cfg.arch_type != "ssm":
         h = h + sinusoidal_pos(positions, cfg.d_model).astype(h.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        h = h * cfg.embedding_multiplier
     return constrain_batch(ctx, h)
 
 
@@ -523,7 +632,10 @@ def unembed(ctx: ApplyCtx, params, h):
     else:
         h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
-    return jnp.einsum("bsd,dv->bsv", h, w.astype(h.dtype))
+    logits = jnp.einsum("bsd,dv->bsv", h, w.astype(h.dtype))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def encode(ctx: ApplyCtx, params, enc_feats):
@@ -576,6 +688,9 @@ def forward_train(ctx: ApplyCtx, params, batch) -> Tuple[jax.Array, jax.Array]:
     if cfg.is_encoder_decoder:
         enc_out, enc_pos = encode(ctx, params, batch["enc_feats"])
     h = embed(ctx, params, tokens, positions, batch.get("vision_embeds"))
+    if cfg.layer_types is not None:
+        h, aux, _ = run_kinds(ctx, params["layers"], h, positions)
+        return unembed(ctx, params, h), aux
     n_pro = _n_prologue(cfg)
     aux = jnp.zeros((), jnp.float32)
     if n_pro:
@@ -614,6 +729,16 @@ def prefill(ctx: ApplyCtx, params, batch, capacity: Optional[int] = None):
         enc_out, enc_pos = encode(ctx, params, batch["enc_feats"])
     with jax.named_scope("embed"):
         h = embed(ctx, params, tokens, positions, batch.get("vision_embeds"))
+    if cfg.layer_types is not None:
+        # the ring is sized to its capacity from the start: each layer
+        # writes its K/V or state into the zeroed leaves in place
+        leaves = abstract_cache(cfg, b, max(capacity or s, s))["main"]
+        zeros = {k: jnp.zeros(a.shape, a.dtype) for k, a in leaves.items()}
+        h, _, cache["main"] = run_kinds(ctx, params["layers"], h, positions, zeros)
+        cache["length"] = jnp.asarray(s, jnp.int32)
+        with jax.named_scope("head"):
+            logits = unembed(ctx, params, h[:, -1:])
+        return cache, logits
     n_pro = _n_prologue(cfg)
     if n_pro:
         h, _, c_pro = run_prologue(
@@ -696,6 +821,21 @@ def abstract_cache(
             c["cross_v"] = sds((n, batch, el, hkv, hd))
         return c
 
+    if cfg.layer_types is not None:  # each leaf over the layers of its kind
+        n_attn = cfg.layer_types.count(ATTENTION)
+        n_mamba = cfg.layer_types.count(MAMBA)
+        c: Dict[str, Any] = {}
+        if n_attn:
+            hd, hkv = cfg.resolved_head_dim, cfg.n_kv_heads
+            c["k"] = sds((n_attn, batch, hkv, w, hd))
+            c["v"] = sds((n_attn, batch, hkv, w, hd))
+        if n_mamba:
+            s = cfg.ssm
+            conv_dim = s.inner(cfg.d_model) + 2 * s.d_state
+            c["ssm"] = sds((n_mamba, batch, s.n_ssm_heads(cfg.d_model), s.headdim,
+                            s.d_state))
+            c["conv"] = sds((n_mamba, batch, s.d_conv - 1, conv_dim))
+        return {"main": c, "length": jax.ShapeDtypeStruct((), jnp.int32)}
     n_pro = _n_prologue(cfg)
     cache: Dict[str, Any] = {"main": layer_cache(cfg.n_layers - n_pro)}
     if n_pro:
@@ -750,7 +890,7 @@ def layer_decode(ctx: ApplyCtx, lp, window, cache, i, h, pos, pos3):
         return out
 
     if cfg.arch_type == "ssm":
-        return h + ssm_decode(), cache
+        return h + _residual(cfg, ssm_decode()), cache
 
     if cfg.mla is not None:
         from repro.models.mla import _latent  # shared projection helper
@@ -770,31 +910,13 @@ def layer_decode(ctx: ApplyCtx, lp, window, cache, i, h, pos, pos3):
                 _layer_slab(cache, "krope", i).astype(hn.dtype), kv_pos,
             )
     else:
-        with jax.named_scope("attn"):
-            q, k, v = _qkv(cfg, lp["attn"], hn, hn)
-            q, k = _rope_qk(cfg, q, k, pos, pos3)
-        w = cache["k"].shape[SLOT_AXIS["k"]]
-        slot = t % w
-        with jax.named_scope("kv_write"):
-            _write(cache, "k", i, k, slot)
-            _write(cache, "v", i, v, slot)
-        with jax.named_scope("attn"):
-            kv_pos = jnp.broadcast_to(_ring_kv_pos(t, w), (b, w))
-            attn_out = attention(
-                q, _layer_slab(cache, "k", i).astype(hn.dtype),
-                _layer_slab(cache, "v", i).astype(hn.dtype), pos, kv_pos,
-                causal=True, window=window, rcfg=ctx.rcfg,
-            )
-            attn_out = attn_out.reshape(b, 1, -1)
-            attn_out = jnp.einsum(
-                "bse,ed->bsd", attn_out, lp["attn"]["wo"].astype(hn.dtype)
-            )
+        attn_out = _attn_decode(ctx, lp["attn"], hn, cache, i, pos, pos3, window)
 
     if cfg.arch_type == "hybrid":
         g = jax.nn.sigmoid(lp["mix_gate"].astype(jnp.float32))
         attn_out = (g[0] * attn_out + g[1] * ssm_decode()).astype(hn.dtype)
 
-    h = h + attn_out
+    h = h + _residual(cfg, attn_out)
 
     if cfg.is_encoder_decoder:
         hc = _norm(cfg, lp, "ln_cross", h)
@@ -811,7 +933,60 @@ def layer_decode(ctx: ApplyCtx, lp, window, cache, i, h, pos, pos3):
     hn2 = _norm(cfg, lp, "ln2", h)
     with jax.named_scope("mlp"):
         ff, _ = _ffn(ctx, lp["ffn"], hn2)
-    return h + ff, cache
+    return h + _residual(cfg, ff), cache
+
+
+def _attn_decode(ctx: ApplyCtx, ap: dict, hn, cache: dict, i, pos, pos3, window):
+    """One token's GQA attention through layer ``i``: its K/V written at
+    the ring slot of ``cache`` (a dict updated in place), then attention
+    over the layer's updated slab and the output projection."""
+    cfg = ctx.cfg
+    b = hn.shape[0]
+    t = pos[0, 0]
+    with jax.named_scope("attn"):
+        q, k, v = _qkv(cfg, ap, hn, hn)
+        q, k = _rope_qk(cfg, q, k, pos, pos3)
+    w = cache["k"].shape[SLOT_AXIS["k"]]
+    slot = t % w
+    with jax.named_scope("kv_write"):
+        _write(cache, "k", i, k, slot)
+        _write(cache, "v", i, v, slot)
+    with jax.named_scope("attn"):
+        kv_pos = jnp.broadcast_to(_ring_kv_pos(t, w), (b, w))
+        attn_out = attention(
+            q, _layer_slab(cache, "k", i).astype(hn.dtype),
+            _layer_slab(cache, "v", i).astype(hn.dtype), pos, kv_pos,
+            causal=True, window=window, rcfg=ctx.rcfg,
+            scale=cfg.attention_multiplier,
+        )
+        attn_out = attn_out.reshape(b, 1, -1)
+        return jnp.einsum("bse,ed->bsd", attn_out, ap["wo"].astype(hn.dtype))
+
+
+def kind_layer_decode(ctx: ApplyCtx, kind: str, lp: dict, cache: dict, i, h, pos):
+    """One-token decode through layer ``i`` of its kind: the Mamba-2
+    state and conv tail replaced in place, or the token's K/V written at
+    its ring slot; then the FFN. Returns (h, cache)."""
+    cfg = ctx.cfg
+    hn = _norm(cfg, lp, "ln1", h)
+    cache = dict(cache)
+    if kind == MAMBA:
+        with jax.named_scope("ssm"):
+            out, st, cv = ssm_lib.mamba2_decode(
+                cfg, lp["mamba"], hn,
+                _layer_slab(cache, "ssm", i).astype(jnp.float32),
+                _layer_slab(cache, "conv", i).astype(hn.dtype),
+            )
+        with jax.named_scope("ssm_write"):
+            _write(cache, "ssm", i, st)
+            _write(cache, "conv", i, cv)
+    else:
+        out = _attn_decode(ctx, lp["attn"], hn, cache, i, pos, None, None)
+    h = h + _residual(cfg, out)
+    hn2 = _norm(cfg, lp, "ln2", h)
+    with jax.named_scope(_ffn_scope(lp["ffn"])):
+        ff, _ = _ffn(ctx, lp["ffn"], hn2)
+    return h + _residual(cfg, ff), cache
 
 
 def decode_step(ctx: ApplyCtx, params, cache, tokens):
@@ -833,8 +1008,30 @@ def decode_step(ctx: ApplyCtx, params, cache, tokens):
     )
     with jax.named_scope("embed"):
         h = embed(ctx, params, tokens, pos, None)
-    n_pro = _n_prologue(cfg)
     new_cache = dict(cache)
+    if cfg.layer_types is not None:
+        stack = cache["main"]
+        layers = params["layers"]
+        for start, end, kind, first in kind_runs(cfg):
+
+            def kind_body(carry, xs, _kind=kind):
+                hh, kv = carry
+                i, j = xs
+                lp = _layer_params(layers, i, j, _kind)
+                hh, kv = kind_layer_decode(ctx, _kind, lp, kv, j, hh, pos)
+                return (constrain_batch(ctx, hh), kv), None
+
+            if end - start == 1:
+                (h, stack), _ = kind_body((h, stack), (start, first))
+            else:
+                (h, stack), _ = jax.lax.scan(
+                    kind_body, (h, stack), _run_ids(start, end, first))
+        new_cache["main"] = stack
+        new_cache["length"] = t + 1
+        with jax.named_scope("head"):
+            logits = unembed(ctx, params, h)
+        return new_cache, logits
+    n_pro = _n_prologue(cfg)
     if n_pro:
         windows = layer_windows(cfg, n_pro)
         for i in range(n_pro):

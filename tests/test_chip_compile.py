@@ -196,3 +196,80 @@ def test_qwen_full_engine_decode_updates_cache_in_place(one_chip, qwen_full):
     slabs = [n for n, d in _OP.findall(_computation(text, body))
              if dims(d) == sorted(k.shape[1:])]
     assert not slabs, slabs
+
+
+@pytest.fixture
+def granite_h_full(one_chip, monkeypatch):
+    """granite-4.0-h-small's chip share (20 layers, 9 of 72 experts) with
+    bf16 params as the benchmark serves it: the flash, SSD and grouped
+    expert kernels compiled, not interpreted."""
+    import importlib.util
+    import json
+    from pathlib import Path
+
+    import repro.kernels.runtime as kernel_runtime
+
+    for mod in ("repro.kernels.flash_attention.flash_attention",
+                "repro.kernels.ssd_scan.ssd_scan"):
+        monkeypatch.setattr(importlib.import_module(mod), "default_interpret",
+                            lambda: False)
+    monkeypatch.setattr(kernel_runtime, "default_interpret", lambda: False)
+    chip = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    spec = importlib.util.spec_from_file_location(
+        "granite_moe_hybrid_program", chip / "programs" / "granite_moe_hybrid.py")
+    program = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(program)
+    config = json.loads((chip / "configs" / "granite-4.0-h-small.json").read_text())
+    cfg = program.program_config(config)
+    rcfg = serving_config(param_dtype="bfloat16", use_pallas=True)
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        abstract_model_params(cfg, rcfg),
+    )
+    return ApplyCtx(cfg, rcfg, None), params
+
+
+HYBRID_BATCH, HYBRID_PROMPT, HYBRID_CAPACITY = 32, 512, 1024  # the offline_b32 cell
+
+
+def test_granite_h_engine_decode_updates_state_in_place(one_chip, granite_h_full):
+    """The hybrid's decode at the cell's shapes fits HBM with every cache
+    leaf (K/V of 2 attention layers, SSM and conv state of 18 Mamba
+    layers) aliased to the output, no copy of the SSM state or K/V, and
+    the held experts in the grouped kernel. (The 58 MB conv leaf is
+    copied into fast memory once a step, as mamba2-2.7b's state is.)"""
+    ctx, params = granite_h_full
+    cache = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        abstract_cache(ctx.cfg, HYBRID_BATCH, HYBRID_CAPACITY),
+    )
+    kv = {k: v for k, v in cache.items() if k != "length"}
+    tokens = _sds(one_chip, (HYBRID_BATCH, 1), jnp.int32)
+    c = make_engine_decode(ctx).lower(params, kv, cache["length"], tokens).compile()
+    leaves = cache["main"]
+    assert set(leaves) == {"k", "v", "ssm", "conv"}
+    assert leaves["ssm"].shape == (18, 32, 128, 64, 128)
+    assert c.memory_analysis().alias_size_in_bytes >= sum(
+        a.size * 2 for a in leaves.values())
+    assert _hbm_bytes(c) <= HBM_BYTES, c.memory_analysis()
+    text = c.as_text()
+    for name in ("ssm", "k"):
+        shape = ",".join(str(n) for n in leaves[name].shape)
+        assert not re.search(rf"= bf16\[{shape}\]\S* copy\(", text), name
+    assert "gmm" in text and "tpu_custom_call" in text
+
+
+def test_granite_h_prefill_fits_hbm(one_chip, granite_h_full):
+    """The prefill of 32 prompts of 512 (two SSD chunks) with the Pallas
+    SSD, flash and grouped expert kernels fits HBM."""
+    ctx, params = granite_h_full
+    tokens = _sds(one_chip, (HYBRID_BATCH, HYBRID_PROMPT), jnp.int32)
+    c = _compile(
+        lambda p, t: make_prefill_step(ctx, capacity=HYBRID_CAPACITY)(p, {"tokens": t}),
+        params,
+        tokens,
+    )
+    text = c.as_text()
+    for kernel in ("ssd_pallas", "flash_attention_bhsd", "gmm"):
+        assert f"%{kernel}" in text, kernel
+    assert _hbm_bytes(c) <= HBM_BYTES, c.memory_analysis()
